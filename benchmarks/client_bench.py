@@ -35,10 +35,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks.common import (  # noqa: E402,F401
-    enable_compilation_cache,
-    merge_rows,
-)
+from benchmarks.common import merge_rows  # noqa: E402
 from repro.client import (  # noqa: E402
     ClientSession,
     MockProvider,

@@ -1,58 +1,17 @@
 """Shared benchmark harness utilities: cell execution, CSV emission,
-and the persistent JAX compilation cache every benchmark driver enables
-on import."""
+and the persistent JAX compilation cache (`repro.compile_cache`) every
+benchmark driver enables on import."""
 from __future__ import annotations
 
 import csv
 import os
 import time
 
-# Benchmarks run on XLA's legacy CPU runtime: the thunk runtime's
-# dispatch overhead roughly doubles the per-call latency of the small
-# fused session/tick programs these drivers time (it washes out on the
-# big scan programs).  Set before the first `import jax` in the process
-# — `enable_compilation_cache()` below imports jax, and every driver
-# imports this module first.  Deliberately scoped to benchmarks: the
-# legacy LLVM emitter contracts FMAs inside fusion kernels *below* the
-# HLO level, so `core.numerics.pinned` cannot equalize rounding between
-# the dense and windowed engine programs there (1-ulp severity drift in
-# limiter scenarios; optimized HLO is bit-identical across runtimes —
-# verified by diffing `.compile().as_text()`).  The test suite runs the
-# default runtime, where the cross-engine bit-exact contract holds.
-_XLA_FLAG = "--xla_cpu_use_thunk_runtime=false"
-if _XLA_FLAG not in os.environ.get("XLA_FLAGS", ""):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " " + _XLA_FLAG).strip()
-
+from repro.compile_cache import enable_compilation_cache
 from repro.core.policy import PolicyConfig
 from repro.sim import SimConfig, WorkloadConfig, run_cell, summarize
 
 TABLE_DIR = os.path.join(os.path.dirname(__file__), "..", "paper_results", "tables")
-
-
-def enable_compilation_cache() -> str:
-    """Turn on JAX's persistent compilation cache for benchmark runs.
-
-    The scheduler microbenchmarks pay ~1-4 s of XLA compile per (K, B,
-    N, W) cell (BENCH_scheduler.json `compile_seconds`), and the sweep
-    grid keeps growing — a warm cache turns repeat local runs and CI
-    re-runs into pure execution.  Honors `JAX_COMPILATION_CACHE_DIR`
-    (the CI cache points it at a restored directory); defaults to a
-    gitignored `.jax_cache/` at the repo root.  Thresholds drop to zero
-    so the many small-but-numerous scheduler programs are cached too.
-    Returns the cache directory.
-    """
-    import jax
-
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.abspath(os.path.join(os.path.dirname(__file__), "..",
-                                     ".jax_cache")))
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    return cache_dir
 
 
 # every benchmark driver imports this module first, so enabling here

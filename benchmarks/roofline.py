@@ -268,6 +268,8 @@ def main():
     ap.add_argument("--shape")
     args = ap.parse_args()
     if args.probe:
+        from repro.compile_cache import enable_compilation_cache
+        enable_compilation_cache()
         run_probes([args.arch] if args.arch else None,
                    [args.shape] if args.shape else None)
     if args.report or not args.probe:

@@ -477,8 +477,9 @@ class ClientSession:
         self._t0: Optional[float] = None
         self._defer_hint = float("inf")
         self._timeout_mult = np.asarray(policy.timeout_mult, np.float32)
-        # reused per-poll transfer buffers (jit copies them at call
-        # time, so in-place refills between calls are safe)
+        # reused per-poll transfer buffers.  A jitted call may read a
+        # NumPy argument in place (zero-copy on the CPU) until it ends,
+        # so they are refilled only after the poll's blocking pull.
         self._comp = np.empty((2, w), np.float32)
         self._comp[0] = w          # scatter sentinel: dropped by the set
         self._comp[1] = np.inf
@@ -488,7 +489,7 @@ class ClientSession:
         self._watchdog = (Watchdog(resilience, self.phys)
                           if resilience is not None else None)
         # (K,) per-class deficit charge for this epoch's resubmissions;
-        # reused transfer buffer like _comp (jit copies at call time)
+        # reused transfer buffer like _comp
         self._resub_charge = np.zeros(self._k, np.float32)
         self._tick = _tick_for(policy, self.phys, cfg.max_grants,
                                cfg.backend)
@@ -802,11 +803,6 @@ class ClientSession:
         # the dispatch is async: the mirror bookkeeping below depends
         # only on host state, so it runs while the device executes —
         # the blocking summary pull comes after
-        if ncomp:
-            self._comp[0, :ncomp] = w
-            self._comp[1, :ncomp] = np.inf
-        if extra and self._resub_charge.any():
-            self._resub_charge[:] = 0.0
 
         # 5. mirror compaction (lockstep with the device scatter)
         nt = n_alive + n_stage
@@ -837,6 +833,12 @@ class ClientSession:
         summary = np.asarray(summary)  # the one device->host pull
         if prof is not None:
             _tp4 = time.perf_counter()
+        # the tick has ended, so its transfer buffers may be reset now
+        if ncomp:
+            self._comp[0, :ncomp] = w
+            self._comp[1, :ncomp] = np.inf
+        if extra and self._resub_charge.any():
+            self._resub_charge[:] = 0.0
         actions = summary[0:b].astype(np.int32)
         idxs = summary[b:2 * b].astype(np.int32)
         infl_at = summary[2 * b:3 * b].astype(np.int32)

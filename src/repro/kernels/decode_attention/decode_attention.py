@@ -8,9 +8,10 @@ tiny ((G, hd) after GQA folding) so the kernel is HBM-bandwidth-bound by
 K/V traffic — exactly the regime the roofline analysis shows for
 decode_32k, which is why this is a kernel-worthy hot spot.
 
-A small TPU-specific twist: the single query token is broadcast to an
-8-row tile so the MXU/VPU see aligned shapes (rows 1..7 are masked out of
-the final write).
+The wrapper moves the head axis ahead of the sequence axis and gives
+the query and the validity mask a unit row axis, so every block's two
+minor dims are either (kv block, head_dim) or (1, lanes) — the layouts
+Mosaic tiles — and batch/head dims are squeezed out of the kernel's view.
 """
 from __future__ import annotations
 
@@ -34,15 +35,15 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0, :].astype(jnp.float32)[None, :]   # (1, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)         # (bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)         # (bk, hd)
-    valid = valid_ref[:]                              # (bk,) bool/int32
+    q = q_ref[...].astype(jnp.float32)                # (1, hd)
+    k = k_ref[...].astype(jnp.float32)                # (bk, hd)
+    v = v_ref[...].astype(jnp.float32)                # (bk, hd)
+    valid = valid_ref[...]                            # (1, bk) int32
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale    # (1, bk)
-    s = jnp.where(valid[None, :] > 0, s, NEG_INF)
+    s = jnp.where(valid > 0, s, NEG_INF)
 
     m_prev = m_scr[...]
     m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -59,7 +60,7 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[0, 0, :] = (acc / jnp.maximum(l_new, 1e-30))[0].astype(o_ref.dtype)
+        o_ref[...] = (acc / jnp.maximum(l_new, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
@@ -76,23 +77,26 @@ def decode_attention(q, k, v, valid, *, bk: int = 1024,
     scale = 1.0 / (hd ** 0.5)
 
     kernel = functools.partial(_kernel, bk=bk, scale=scale, nk=nk)
-    valid_i = valid.astype(jnp.int32)
+    valid_i = valid.astype(jnp.int32)[None, :]               # (1, S)
+    q_spec = pl.BlockSpec((None, None, 1, hd), lambda b, h, ki: (b, h, 0, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, hd),
+                           lambda b, h, ki: (b, h // G, ki, 0))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=(B, H, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, hd), lambda b, h, ki: (b, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((bk,), lambda b, h, ki: (ki,)),
+            q_spec, kv_spec, kv_spec,
+            pl.BlockSpec((1, bk), lambda b, h, ki: (0, ki)),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd), lambda b, h, ki: (b, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, hd), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, valid_i)
+    )(q[:, :, None, :], k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3),
+      valid_i)
+    return out[:, :, 0, :]

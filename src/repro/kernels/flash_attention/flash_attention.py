@@ -6,8 +6,12 @@ grid dimension is sequential on TPU, so the online-softmax state
 and the output block is written on the final kv step.
 
 GQA is handled in the k/v index_map (q head h reads kv head h // group),
-so no head replication is materialized.  Causal + sliding-window masking
-is computed from block offsets with iota — masked *inside* the exponent.
+so no head replication is materialized.  The wrapper moves the head axis
+ahead of the sequence axis, so every block's two minor dims are
+(seq block, head_dim) — the layout Mosaic tiles — and the head and
+batch dims are squeezed out of the kernel's view.  Causal +
+sliding-window masking is computed from block offsets with iota —
+masked *inside* the exponent.
 
 VMEM budget per program (bq = bk = 512, hd <= 256, f32 compute):
 q/k/v blocks 3*512*256*4 = 1.5 MB, score tile 512*512*4 = 1 MB, scratch
@@ -37,9 +41,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)       # (bq, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)       # (bk, hd)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)       # (bk, hd)
+    q = q_ref[...].astype(jnp.float32)              # (bq, hd)
+    k = k_ref[...].astype(jnp.float32)              # (bk, hd)
+    v = v_ref[...].astype(jnp.float32)              # (bk, hd)
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
@@ -68,8 +72,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(ki == nk - 1)
     def _finish():
-        o_ref[0, :, 0, :] = (
-            acc / jnp.maximum(l_new, 1e-30)).astype(o_ref.dtype)
+        o_ref[...] = (acc / jnp.maximum(l_new, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -96,20 +99,21 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     kernel = functools.partial(
         _kernel, bq=bq, bk=bk, window=window, scale=scale, nk=nk)
 
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, bq, hd),
+                          lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((None, None, bk, hd),
+                           lambda b, h, qi, ki: (b, h // G, ki, 0))
+    out = pl.pallas_call(
         kernel,
         grid=(B, H, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, hd), lambda b, h, qi, ki: (b, ki, h // G, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd), lambda b, h, qi, ki: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, hd), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, Sq, hd), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),    # running max
             pltpu.VMEM((bq, 1), jnp.float32),    # running denominator
             pltpu.VMEM((bq, hd), jnp.float32),   # weighted-value accumulator
         ],
         interpret=interpret,
-    )(q, k, v)
+    )(*(x.transpose(0, 2, 1, 3) for x in (q, k, v)))
+    return out.transpose(0, 2, 1, 3)

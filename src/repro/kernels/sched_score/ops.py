@@ -10,7 +10,6 @@ import jax.numpy as jnp
 
 from repro.kernels import interpret_mode
 from repro.kernels.sched_score.sched_score import (
-    sched_compact_topb as _compact_topb_kernel,
     sched_score_argmax as _argmax_kernel,
     sched_score_topb as _topb_kernel,
 )
@@ -69,36 +68,3 @@ def sched_score_topb(wait, cost, urgency, mask, weights, b: int, route=None,
     return _topb_kernel(wait, cost, urgency, mask, weights, route, b=b,
                         blk=blk, interpret=interpret_mode())
 
-
-def sched_compact_topb(slot_req, alive, wait, cost, urgency, weights, b: int,
-                       route=None, *, blk: int = 128,
-                       interpret: bool | None = None):
-    """Fused tick megakernel: compaction scatter + score + partial top-B
-    in one VMEM pass over a slot pool of any width w >= 1.
-
-    slot_req: (w,) int request ids (slot order, pre-compaction); alive:
-    (w,) bool survivors; wait/cost/urgency: (w,) f32 score features in
-    the same slot order; weights: (4,).  Returns (compacted (w,) i32
-    with -1 tail sentinels, n_live () i32, idx (b,) i32 in compacted
-    coordinates, score (b,) f32) — bit-exact with the two-pass path
-    (XLA cumsum-scatter compaction, then `sched_score_topb` over the
-    compacted pool), including first-occurrence ties and the exhausted
-    region when b exceeds the live count.  Padding lanes are
-    alive=False at the tail: they never shift compacted positions and
-    rank with the other dead slots, which the exhausted-region rule
-    replaces with (rank, NEG) sentinels either way.  `route` (w,) f32
-    enables the fleet route term with a (5,) weights vector
-    [..., w_route]."""
-    w = slot_req.shape[0]
-    b = min(int(b), w)
-    wait, cost, urgency, alive, route, blk = _pad_queue(
-        wait, cost, urgency, alive, blk, route)
-    pad = wait.shape[0] - w
-    if pad:
-        slot_req = jnp.concatenate(
-            [slot_req.astype(jnp.int32), jnp.full((pad,), -1, jnp.int32)])
-    interp = interpret_mode() if interpret is None else interpret
-    comp, n_live, idx, score = _compact_topb_kernel(
-        slot_req, alive, wait, cost, urgency, weights, route, b=b, blk=blk,
-        interpret=interp)
-    return comp[:w], n_live, idx, score

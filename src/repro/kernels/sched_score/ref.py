@@ -47,27 +47,3 @@ def sched_score_topb_ref(wait, cost, urgency, mask, weights, b: int,
     vals, idx = jax.lax.top_k(score, b)
     return idx.astype(jnp.int32), vals
 
-
-@functools.partial(jax.jit, static_argnames=("b",))
-def sched_compact_topb_ref(slot_req, alive, wait, cost, urgency, weights,
-                           b: int, route=None):
-    """Two-pass oracle for the fused tick megakernel: the engine's XLA
-    cumsum-scatter compaction (stable, -1 tail sentinels) followed by
-    the top-B ranking over the *compacted* pool with mask = index <
-    n_live.  Returns (compacted (w,) i32, n_live () i32, idx (b,) i32
-    in compacted coordinates, score (b,) f32)."""
-    w = slot_req.shape[0]
-    pos = jnp.cumsum(alive.astype(jnp.int32)) - 1
-    target = jnp.where(alive, pos, w)
-    creq = jnp.full((w,), -1, jnp.int32).at[target].set(
-        slot_req.astype(jnp.int32), mode="drop")
-    cwait = jnp.zeros((w,), jnp.float32).at[target].set(wait, mode="drop")
-    ccost = jnp.ones((w,), jnp.float32).at[target].set(cost, mode="drop")
-    curg = jnp.zeros((w,), jnp.float32).at[target].set(urgency, mode="drop")
-    croute = None if route is None else \
-        jnp.zeros((w,), jnp.float32).at[target].set(route, mode="drop")
-    n_live = alive.sum().astype(jnp.int32)
-    mask = jnp.arange(w) < n_live
-    idx, score = sched_score_topb_ref(cwait, ccost, curg, mask, weights, b,
-                                      croute)
-    return creq, n_live, idx, score

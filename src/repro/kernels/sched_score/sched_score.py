@@ -1,43 +1,30 @@
-"""Ordering-layer scoring kernels (the paper's §3.1.2 hot spot at
+"""Ordering-layer scoring kernel (the paper's §3.1.2 hot spot at
 production queue depths).
 
-`sched_score_argmax` fuses the feasible-set score
+`sched_score_topb` fuses the feasible-set score
 
     score = w1 * (wait / cost) - w2 * (cost / ref) + w3 * urgency
 
-with the masked argmax reduction in a single VMEM pass over the queue —
-at 10^5+ pending requests the jnp version materializes the score vector
-in HBM and reads it back for the argmax; the fused kernel streams each
-block once.  Grid = (num_blocks,) with the running (best_score, best_idx)
-pair in scratch, written out on the last block.
-
-`sched_score_topb` generalizes it to a fused partial top-B: one tiled
-pass computes each block's scores in VMEM, extracts the block's local
-top-B by B successive masked argmaxes, and tree-combines into a running
-best-B scratch set (a strict replace-worst merge).  The combine is
-associative with the blocks processed in index order, and the strict
-(`>` only) eviction rule makes ties resolve to the earliest index —
-bit-identical to `lax.top_k`'s first-occurrence semantics, which the
-windowed scheduler's bit-exact contract relies on.  The final block
-selection-sorts the scratch set into (idx, score) rows, best first.
-Compared with `lax.top_k` over the full (K, N) score matrix this
+with a partial top-B: one tiled pass computes each block's scores in
+VMEM, extracts the block's local top-B by B successive masked argmaxes,
+and merges them into a running best-B set (a strict replace-worst
+merge).  The merge is associative with the blocks processed in index
+order, and the strict (`>` only) eviction rule makes ties resolve to
+the earliest index — bit-identical to `lax.top_k`'s first-occurrence
+semantics, which the windowed scheduler's bit-exact contract relies on.
+The final block selection-sorts the set into (idx, score) rows, best
+first.  Compared with `lax.top_k` over the full (K, N) score matrix this
 streams each element once and keeps only O(B) state.
+`sched_score_argmax` is its b=1 column.
 
-`sched_compact_topb` is the tick megakernel: it fuses the windowed
-engine's per-tick compaction scatter with the score + partial top-B
-ranking in a single Pallas pass, so the slot pool is read from HBM
-once per tick instead of once for the XLA cumsum-scatter and again for
-the ranking kernel.  The compaction is expressed as a (blk, W) masked
-max-reduction per output block (exact: a stable compaction routes at
-most one live slot to each output lane, dead lanes contribute the -1
-sentinel), and the scores are computed on the *uncompacted* features —
-compaction only permutes values, so scoring before or after it is the
-same arithmetic, and the stable order means slot-order ties are
-compacted-order ties.  Ranks at or beyond the live count are
-overwritten with (rank, NEG) sentinel rows, matching `lax.top_k` over
-the compacted sentinel tail bit for bit.
+TPU layout: every vector the kernel touches is 2-D — feature rows are
+(1, blk) slices, reductions keep their dims, and the running set and
+both outputs are lane-dense (1, 128) rows (the (b,) results are sliced
+outside the kernel).  Mosaic cannot store a scalar to VMEM, so no value
+is ever written element by element; the weights are scalars and live in
+SMEM.
 
-Fleet route term (DESIGN.md §10): every kernel optionally takes a fifth
+Fleet route term (DESIGN.md §10): the kernel optionally takes a fifth
 feature row `route` (per-request predicted queue delay at its best
 endpoint, seconds) and a fifth weight `w_route`, subtracting
 `w_route * route` from the score.  Presence is static (`has_route`),
@@ -55,116 +42,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
+_BPAD = 128  # lane width of the running set and the output rows;
+             # entries >= b are inert (+inf/-inf guards)
+_BIG = 2**31 - 1
 
 
 def _score_rows(arr_ref, w_ref, has_route: bool):
-    """Shared score evaluation: feature rows [wait, cost, urg(, route),
-    mask] against weights [w1, w2, w3, ref_tok(, w_route)].  The route
-    term is subtracted — a congested best endpoint ranks the request
-    later.  `has_route` is trace-static, so the four-row program is
-    unchanged byte for byte when off."""
-    wait = arr_ref[0, :]
-    cost = arr_ref[1, :]
-    urg = arr_ref[2, :]
-    mask = arr_ref[4 if has_route else 3, :]
+    """Score evaluation over (1, blk) feature rows [wait, cost, urg(,
+    route), mask] against SMEM weights [w1, w2, w3, ref_tok(, w_route)].
+    The route term is subtracted — a congested best endpoint ranks the
+    request later.  `has_route` is trace-static, so the four-row program
+    is unchanged byte for byte when off."""
+    m = 4 if has_route else 3
+    wait = arr_ref[0:1, :]
+    cost = arr_ref[1:2, :]
+    urg = arr_ref[2:3, :]
+    mask = arr_ref[m:m + 1, :]
     w1, w2, w3, ref_tok = w_ref[0, 0], w_ref[0, 1], w_ref[0, 2], w_ref[0, 3]
 
     c = jnp.maximum(cost, 1.0)
     score = w1 * (wait / c) - w2 * (c / ref_tok) + w3 * urg
     if has_route:
-        score = score - w_ref[0, 4] * arr_ref[3, :]
+        score = score - w_ref[0, 4] * arr_ref[3:4, :]
     return score, mask
 
 
-def _kernel(arr_ref, w_ref, out_idx_ref, out_score_ref, best_ref, *,
-            blk: int, nb: int, has_route: bool):
-    bi = pl.program_id(0)
-
-    @pl.when(bi == 0)
-    def _init():
-        best_ref[0, 0] = NEG
-        best_ref[0, 1] = -1.0
-
-    score, mask = _score_rows(arr_ref, w_ref, has_route)
-    score = jnp.where(mask > 0, score, NEG)
-
-    j = jnp.argmax(score)
-    s = score[j]
-    prev_s = best_ref[0, 0]
-    take = s > prev_s
-    best_ref[0, 0] = jnp.where(take, s, prev_s)
-    best_ref[0, 1] = jnp.where(
-        take, (bi * blk + j).astype(jnp.float32), best_ref[0, 1])
-
-    @pl.when(bi == nb - 1)
-    def _finish():
-        out_idx_ref[0] = best_ref[0, 1].astype(jnp.int32)
-        out_score_ref[0] = best_ref[0, 0]
-
-
-def _stack_features(wait, cost, urgency, mask, route):
-    """(rows, n) feature stack: [wait, cost, urg(, route), mask].  The
-    mask row stays last so `has_route` only inserts, never reorders."""
-    rows = [wait, cost, urgency]
-    if route is not None:
-        rows.append(route)
-    rows.append(mask.astype(jnp.float32))
-    return jnp.stack(rows)
-
-
-@functools.partial(jax.jit, static_argnames=("blk", "interpret"))
-def sched_score_argmax(wait, cost, urgency, mask, weights, route=None, *,
-                       blk: int = 2048, interpret: bool = False):
-    """wait/cost/urgency: (n,) f32; mask: (n,) bool; weights: (4,)
-    [w_wait, w_size, w_urg, ref_tokens]. Returns (best_idx i32, best_score).
-    n must be a multiple of blk (callers pad with mask=False).
-    `route` (n,) f32 enables the fleet route term with a (5,) weights
-    vector [..., w_route]."""
-    n = wait.shape[0]
-    blk = min(blk, n)
-    assert n % blk == 0, "pad the queue to a block multiple"
-    nb = n // blk
-    has_route = route is not None
-    nf = 5 if has_route else 4
-    arr = _stack_features(wait, cost, urgency, mask, route)  # (nf, n)
-    w = weights.astype(jnp.float32)[None, :]                 # (1, nf)
-
-    kernel = functools.partial(_kernel, blk=blk, nb=nb, has_route=has_route)
-    idx, score = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((nf, blk), lambda b: (0, b)),
-            # (1, nf) weight vector: parameter block, Mosaic pads the
-            # tail lanes; not an accumulator tile (nf is the sublane-
-            # padded feature count, never the lane axis)
-            pl.BlockSpec((1, nf), lambda b: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1,), lambda b: (0,)),
-            pl.BlockSpec((1,), lambda b: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.float32),
-        ],
-        # full (1, 128) lane even though only lanes 0-1 carry state:
-        # a 2-wide minor axis forces Mosaic to pad the tile anyway, and
-        # the explicit width keeps the scratch lane-aligned (RPL005)
-        scratch_shapes=[pltpu.VMEM((1, 128), jnp.float32)],
-        interpret=interpret,
-    )(arr, w)
-    return idx[0], score[0]
-
-
-# ---------------------------------------------------------------------------
-# Fused partial top-B
-# ---------------------------------------------------------------------------
-
-_BPAD = 128  # scratch lane width; entries >= b are inert (+inf/-inf guards)
-
-
-def _topb_kernel(arr_ref, w_ref, out_idx_ref, out_score_ref,
+def _topb_kernel(w_ref, arr_ref, out_idx_ref, out_score_ref,
                  best_s_ref, best_i_ref, *, blk: int, nb: int, b: int,
                  has_route: bool):
     bi = pl.program_id(0)
@@ -180,202 +83,63 @@ def _topb_kernel(arr_ref, w_ref, out_idx_ref, out_score_ref,
 
     score, mask = _score_rows(arr_ref, w_ref, has_route)
     score = jnp.where(mask > 0, score, NEG)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)[0]
+    gidx = bi * blk + jax.lax.broadcasted_iota(jnp.int32, (1, blk), 1)
+    best_s = best_s_ref[...]
+    best_i = best_i_ref[...]
 
-    # local top-B by successive masked argmax (first occurrence), merged
-    # into the running set one candidate at a time.  Candidates arrive in
-    # (score desc, idx asc) order and blocks run in index order, so a
-    # candidate that merely *ties* the running worst is always the later
-    # index — the strict `>` eviction below is exactly top_k's
-    # first-occurrence tie-breaking.
+    # local top-B by successive masked argmax (first occurrence: the
+    # smallest index holding the max), merged into the running set one
+    # candidate at a time.  Candidates arrive in (score desc, idx asc)
+    # order and blocks run in index order, so a candidate that merely
+    # *ties* the running worst is always the later index — the strict
+    # `>` eviction below is exactly top_k's first-occurrence tie-breaking.
     for _ in range(b):
-        s = jnp.max(score)
-        jj = jnp.argmax(score).astype(jnp.int32)
-        gidx = bi * blk + jj
-        score = jnp.where(iota == jj, -jnp.inf, score)
+        s = jnp.max(score, keepdims=True)                       # (1, 1)
+        j = jnp.min(jnp.where(score == s, gidx, _BIG), keepdims=True)
+        score = jnp.where(gidx == j, -jnp.inf, score)
 
-        cur = jnp.where(in_set, best_s_ref[...], jnp.inf)
-        worst = jnp.min(cur)
+        cur = jnp.where(in_set, best_s, jnp.inf)
+        worst = jnp.min(cur, keepdims=True)
         # evict the worst entry; among equal-score entries the one with
         # the LARGEST index (it ranks last under first-occurrence order).
         # Resolve to a single lane: -1 sentinels are not unique, so an
         # index match alone could hit several lanes at once.
-        evict_i = jnp.max(jnp.where(cur == worst, best_i_ref[...], -2))
-        cand = in_set & (cur == worst) & (best_i_ref[...] == evict_i)
-        hit = lane == jnp.max(jnp.where(cand, lane, -1))
-        take = s > worst
-        best_s_ref[...] = jnp.where(hit & take, s, best_s_ref[...])
-        best_i_ref[...] = jnp.where(hit & take, gidx, best_i_ref[...])
+        evict_i = jnp.max(jnp.where(cur == worst, best_i, -2), keepdims=True)
+        cand = in_set & (cur == worst) & (best_i == evict_i)
+        hit = (lane == jnp.max(jnp.where(cand, lane, -1), keepdims=True)) \
+            & (s > worst)
+        best_s = jnp.where(hit, s, best_s)
+        best_i = jnp.where(hit, j, best_i)
+    best_s_ref[...] = best_s
+    best_i_ref[...] = best_i
 
     @pl.when(bi == nb - 1)
     def _finish():
         # selection-sort the set into release order: score desc, ties by
-        # ascending index (first occurrence) — lax.top_k's output order
-        rem_s = best_s_ref[...]
-        rem_i = best_i_ref[...]
-        big = jnp.int32(2**31 - 1)
-        for j in range(b):
-            cur = jnp.where(in_set, rem_s, -jnp.inf)
-            m = jnp.max(cur)
-            sel = jnp.min(jnp.where(cur == m, rem_i, big))
-            out_idx_ref[j] = sel
-            out_score_ref[j] = m
-            used = (cur == m) & (rem_i == sel)
-            rem_s = jnp.where(used, -jnp.inf, rem_s)
-
-
-# ---------------------------------------------------------------------------
-# Fused compaction + score + partial top-B (the tick megakernel)
-# ---------------------------------------------------------------------------
-
-
-def _compact_topb_kernel(req_ref, arr_ref, w_ref, out_req_ref, out_n_ref,
-                         out_idx_ref, out_score_ref, best_s_ref, best_i_ref,
-                         *, blk: int, nb: int, b: int, w_total: int,
-                         has_route: bool):
-    """One grid step = one compacted output block.
-
-    Every step sees the full (W,) pool in VMEM (the window is capped at
-    a few thousand slots): it rebuilds the alive-prefix positions,
-    scatters its own compacted block via a masked (blk, W) reduction —
-    each output lane receives exactly one survivor or the -1 sentinel,
-    so the max-combine is exact — scores its slot block in place, and
-    merges the block's local top-B into the running scratch set with
-    the same strict-eviction rule as `_topb_kernel`.  Candidate merge
-    order is ascending slot index; the final step translates winners
-    into compacted coordinates (compaction is stable, so slot order and
-    compacted order agree and first-occurrence ties carry over)."""
-    bi = pl.program_id(0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, _BPAD), 1)
-    in_set = lane < b
-
-    @pl.when(bi == 0)
-    def _init():
-        best_s_ref[...] = jnp.full((1, _BPAD), -jnp.inf, jnp.float32)
-        best_i_ref[...] = jnp.full((1, _BPAD), -1, jnp.int32)
-
-    alive = arr_ref[4 if has_route else 3, :] > 0.0   # (W,)
-    req = req_ref[0, :]                               # (W,) i32
-    cum = jnp.cumsum(alive.astype(jnp.int32))         # (W,) inclusive
-    pos = cum - 1                                     # compacted slot of i
-    n_live = cum[w_total - 1]
-    lane_w = jax.lax.broadcasted_iota(jnp.int32, (1, w_total), 1)[0]
-
-    # --- compaction scatter for this output block: out[j] = req[i] where
-    # pos[i] == j & alive[i] (at most one i per j), else the -1 sentinel
-    jg = bi * blk + jax.lax.broadcasted_iota(
-        jnp.int32, (blk, w_total), 0)                 # (blk, W) target rows
-    hit = alive[None, :] & (pos[None, :] == jg)
-    out_req_ref[...] = jnp.max(jnp.where(hit, req[None, :], -1), axis=1)
-
-    # --- this block's slot scores (features are pre-compaction: the
-    # scatter only permutes values, so scoring before or after compaction
-    # is the same arithmetic on the same f32 values)
-    score, _ = _score_rows(arr_ref, w_ref, has_route)
-    in_blk = (lane_w >= bi * blk) & (lane_w < (bi + 1) * blk)
-    # dead slots carry the finite NEG (they may fill the exhausted region,
-    # overwritten below); out-of-block lanes are -inf: not candidates here
-    score = jnp.where(in_blk & alive, score, jnp.where(in_blk, NEG, -jnp.inf))
-
-    for _ in range(b):
-        s = jnp.max(score)
-        jj = jnp.argmax(score).astype(jnp.int32)      # global slot index
-        score = jnp.where(lane_w == jj, -jnp.inf, score)
-
-        cur = jnp.where(in_set, best_s_ref[...], jnp.inf)
-        worst = jnp.min(cur)
-        evict_i = jnp.max(jnp.where(cur == worst, best_i_ref[...], -2))
-        cand = in_set & (cur == worst) & (best_i_ref[...] == evict_i)
-        hit_l = lane == jnp.max(jnp.where(cand, lane, -1))
-        take = s > worst
-        best_s_ref[...] = jnp.where(hit_l & take, s, best_s_ref[...])
-        best_i_ref[...] = jnp.where(hit_l & take, jj, best_i_ref[...])
-
-    @pl.when(bi == nb - 1)
-    def _finish():
-        rem_s = best_s_ref[...]
-        rem_i = best_i_ref[...]
-        big = jnp.int32(2**31 - 1)
+        # ascending index (first occurrence) — lax.top_k's output order;
+        # rank r lands in lane r of the lane-dense output rows
+        rem_s = best_s
+        out_i = jnp.zeros((1, _BPAD), jnp.int32)
+        out_s = jnp.full((1, _BPAD), NEG, jnp.float32)
         for r in range(b):
             cur = jnp.where(in_set, rem_s, -jnp.inf)
-            m = jnp.max(cur)
-            sel = jnp.min(jnp.where(cur == m, rem_i, big))
-            # slot -> compacted coordinates (masked reduction: a dynamic
-            # scalar gather would not lower on all targets)
-            csel = jnp.max(jnp.where(lane_w == sel, pos, -1))
-            # the exhausted region (rank >= n_live) mirrors top_k over the
-            # compacted pool: the sentinel tail ties at NEG, so rank r
-            # resolves to compacted index r exactly
-            exhausted = r >= n_live
-            out_idx_ref[r] = jnp.where(exhausted, r, csel)
-            out_score_ref[r] = jnp.where(exhausted, NEG, m)
-            used = (cur == m) & (rem_i == sel)
-            rem_s = jnp.where(used, -jnp.inf, rem_s)
-        out_n_ref[0] = n_live
+            m = jnp.max(cur, keepdims=True)
+            sel = jnp.min(jnp.where(cur == m, best_i, _BIG), keepdims=True)
+            out_i = jnp.where(lane == r, sel, out_i)
+            out_s = jnp.where(lane == r, m, out_s)
+            rem_s = jnp.where((cur == m) & (best_i == sel), -jnp.inf, rem_s)
+        out_idx_ref[...] = out_i
+        out_score_ref[...] = out_s
 
 
-@functools.partial(jax.jit, static_argnames=("b", "blk", "interpret"))
-def sched_compact_topb(slot_req, alive, wait, cost, urgency, weights,
-                       route=None, *,
-                       b: int, blk: int = 128, interpret: bool = False):
-    """Fused compaction scatter + score + partial top-B over a slot pool.
-
-    slot_req: (w,) i32 request ids; alive: (w,) bool survivors;
-    wait/cost/urgency: (w,) f32 per-slot score features (slot order,
-    pre-compaction); weights: (4,) [w_wait, w_size, w_urg, ref_tokens].
-
-    Returns (compacted (w,) i32 with -1 tail sentinels, n_live () i32,
-    idx (b,) i32 in *compacted* coordinates, score (b,) f32), bit-exact
-    with running the XLA cumsum-scatter compaction followed by
-    `sched_score_topb` over the compacted pool (mask = index < n_live):
-    stable compaction preserves first-occurrence tie order, and the
-    exhausted region (rank >= n_live) yields (rank, NEG) exactly like
-    `lax.top_k` over the sentinel tail.  w must be a multiple of blk
-    (callers pad with alive=False); requires b <= min(w, _BPAD).
-    `route` (w,) f32 enables the fleet route term with a (5,) weights
-    vector [..., w_route]."""
-    w = slot_req.shape[0]
-    blk = min(blk, w)
-    assert w % blk == 0, "pad the pool to a block multiple"
-    assert 0 < b <= min(w, _BPAD), (b, w)
-    nb = w // blk
-    has_route = route is not None
-    nf = 5 if has_route else 4
-    req = slot_req.astype(jnp.int32)[None, :]                 # (1, w)
-    arr = _stack_features(wait, cost, urgency, alive, route)  # (nf, w)
-    wts = weights.astype(jnp.float32)[None, :]                # (1, nf)
-
-    kernel = functools.partial(
-        _compact_topb_kernel, blk=blk, nb=nb, b=b, w_total=w,
-        has_route=has_route)
-    comp, n_live, idx, score = pl.pallas_call(
-        kernel,
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((1, w), lambda g: (0, 0)),
-            pl.BlockSpec((nf, w), lambda g: (0, 0)),
-            # (1, nf) weight vector: parameter block, padded by Mosaic
-            pl.BlockSpec((1, nf), lambda g: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((blk,), lambda g: (g,)),
-            pl.BlockSpec((1,), lambda g: (0,)),
-            pl.BlockSpec((b,), lambda g: (0,)),
-            pl.BlockSpec((b,), lambda g: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((w,), jnp.int32),
-            jax.ShapeDtypeStruct((1,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((1, _BPAD), jnp.float32),
-            pltpu.VMEM((1, _BPAD), jnp.int32),
-        ],
-        interpret=interpret,
-    )(req, arr, wts)
-    return comp, n_live[0], idx, score
+def _stack_features(wait, cost, urgency, mask, route):
+    """(rows, n) feature stack: [wait, cost, urg(, route), mask].  The
+    mask row stays last so `has_route` only inserts, never reorders."""
+    rows = [wait, cost, urgency]
+    if route is not None:
+        rows.append(route)
+    rows.append(mask.astype(jnp.float32))
+    return jnp.stack(rows)
 
 
 @functools.partial(jax.jit, static_argnames=("b", "blk", "interpret"))
@@ -405,22 +169,33 @@ def sched_score_topb(wait, cost, urgency, mask, weights, route=None, *,
         kernel,
         grid=(nb,),
         in_specs=[
+            # the whole (1, nf) weight vector as SMEM scalars
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((nf, blk), lambda g: (0, g)),
-            # (1, nf) weight vector: parameter block, padded by Mosaic
-            pl.BlockSpec((1, nf), lambda g: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((b,), lambda g: (0,)),
-            pl.BlockSpec((b,), lambda g: (0,)),
+            pl.BlockSpec((1, _BPAD), lambda g: (0, 0)),
+            pl.BlockSpec((1, _BPAD), lambda g: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b,), jnp.int32),
-            jax.ShapeDtypeStruct((b,), jnp.float32),
+            jax.ShapeDtypeStruct((1, _BPAD), jnp.int32),
+            jax.ShapeDtypeStruct((1, _BPAD), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, _BPAD), jnp.float32),
             pltpu.VMEM((1, _BPAD), jnp.int32),
         ],
         interpret=interpret,
-    )(arr, w)
-    return idx, score
+    )(w, arr)
+    return idx[0, :b], score[0, :b]
+
+
+@functools.partial(jax.jit, static_argnames=("blk", "interpret"))
+def sched_score_argmax(wait, cost, urgency, mask, weights, route=None, *,
+                       blk: int = 2048, interpret: bool = False):
+    """Fused score + masked argmax: the b=1 column of
+    `sched_score_topb`.  Returns (best_idx i32, best_score f32) with
+    first-occurrence tie-breaking; n must be a multiple of blk."""
+    idx, score = sched_score_topb(wait, cost, urgency, mask, weights, route,
+                                  b=1, blk=blk, interpret=interpret)
+    return idx[0], score[0]

@@ -11,6 +11,12 @@ dim P, state dim N (all VMEM-resident; Q=128, P=64, N=128 => ~0.5 MB):
 The inter-chunk recurrence stays a lax.scan in repro.models.ssm (it is
 O(nc) tiny matvecs — not kernel-worthy); this kernel replaces the
 quadratic intra-chunk part, which dominates SSD FLOPs.
+
+The wrapper moves the head axis ahead of the chunk axis and hands the
+per-step dt/cum vectors in both orientations — (1, Q) rows and (Q, 1)
+columns — so every block's two minor dims are full (Q, P), (Q, N),
+(P, N), (1, Q) or (Q, 1) tiles and the kernel never transposes a
+vector.
 """
 from __future__ import annotations
 
@@ -21,14 +27,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, y_ref, st_ref, *, Q: int):
-    x = x_ref[0, 0, :, 0, :]          # (Q, P) f32
-    Bm = b_ref[0, 0, :, :]            # (Q, N)
-    Cm = c_ref[0, 0, :, :]            # (Q, N)
-    dt = dt_ref[0, 0, :, 0]           # (Q,)
-    cum = cum_ref[0, 0, :, 0]         # (Q,)
+def _kernel(x_ref, b_ref, c_ref, dt_row_ref, cum_row_ref, dt_col_ref,
+            cum_col_ref, y_ref, st_ref, *, Q: int):
+    x = x_ref[...]                    # (Q, P) f32
+    Bm = b_ref[...]                   # (Q, N)
+    Cm = c_ref[...]                   # (Q, N)
+    dt_row = dt_row_ref[...]          # (1, Q)
+    cum_row = cum_row_ref[...]        # (1, Q)
+    dt_col = dt_col_ref[...]          # (Q, 1)
+    cum_col = cum_col_ref[...]        # (Q, 1)
 
-    seg = cum[:, None] - cum[None, :]                       # (Qt, Qs)
+    seg = cum_col - cum_row                                 # (Qt, Qs)
     ti = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     si = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     # mask inside the exponent (avoids inf*0 in the backward pass)
@@ -37,14 +46,14 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, cum_ref, y_ref, st_ref, *, Q: int):
     kernel = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                 # (Qt, Qs)
-    W = kernel * decay * dt[None, :]
-    y_ref[0, 0, :, 0, :] = jax.lax.dot_general(
+    W = kernel * decay * dt_row
+    y_ref[...] = jax.lax.dot_general(
         W, x, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
 
-    tail = jnp.exp(cum[-1] - cum) * dt                      # (Q,)
-    xw = x * tail[:, None]                                  # (Q, P)
-    st_ref[0, 0, 0, :, :] = jax.lax.dot_general(
+    tail = jnp.exp(cum_col[Q - 1:Q, :] - cum_col) * dt_col  # (Q, 1)
+    xw = x * tail                                           # (Q, P)
+    st_ref[...] = jax.lax.dot_general(
         xw, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                 # (P, N)
 
@@ -56,23 +65,28 @@ def ssd_intra(xc, Bc, Cc, dtc, cum, *, interpret: bool = False):
     B, nc, Q, H, P = xc.shape
     N = Bc.shape[-1]
     kernel = functools.partial(_kernel, Q=Q)
-    return pl.pallas_call(
+
+    def head_tile(r, c):  # (B, nc, H, r, c) array -> per-head (r, c) tile
+        return pl.BlockSpec((None, None, None, r, c),
+                            lambda b, k, h: (b, k, h, 0, 0))
+
+    bn_spec = pl.BlockSpec((None, None, Q, N), lambda b, k, h: (b, k, 0, 0))
+    dt_h = dtc.transpose(0, 1, 3, 2)                         # (B, nc, H, Q)
+    cum_h = cum.transpose(0, 1, 3, 2)
+    y, st = pl.pallas_call(
         kernel,
         grid=(B, nc, H),
         in_specs=[
-            pl.BlockSpec((1, 1, Q, 1, P), lambda b, c, h: (b, c, 0, h, 0)),
-            pl.BlockSpec((1, 1, Q, N), lambda b, c, h: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, Q, N), lambda b, c, h: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, Q, 1), lambda b, c, h: (b, c, 0, h)),
-            pl.BlockSpec((1, 1, Q, 1), lambda b, c, h: (b, c, 0, h)),
+            head_tile(Q, P), bn_spec, bn_spec,
+            head_tile(1, Q), head_tile(1, Q), head_tile(Q, 1),
+            head_tile(Q, 1),
         ],
-        out_specs=[
-            pl.BlockSpec((1, 1, Q, 1, P), lambda b, c, h: (b, c, 0, h, 0)),
-            pl.BlockSpec((1, 1, 1, P, N), lambda b, c, h: (b, c, h, 0, 0)),
-        ],
+        out_specs=[head_tile(Q, P), head_tile(P, N)],
         out_shape=[
-            jax.ShapeDtypeStruct((B, nc, Q, H, P), jnp.float32),
+            jax.ShapeDtypeStruct((B, nc, H, Q, P), jnp.float32),
             jax.ShapeDtypeStruct((B, nc, H, P, N), jnp.float32),
         ],
         interpret=interpret,
-    )(xc, Bc, Cc, dtc, cum)
+    )(xc.transpose(0, 1, 3, 2, 4), Bc, Cc, dt_h[..., None, :],
+      cum_h[..., None, :], dt_h[..., None], cum_h[..., None])
+    return y.transpose(0, 1, 3, 2, 4), st

@@ -14,13 +14,8 @@ from repro.kernels.decode_attention.ops import decode_attention
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import flash_attention_ref
-from repro.kernels.sched_score.ops import (
-    sched_compact_topb,
-    sched_score_argmax,
-    sched_score_topb,
-)
+from repro.kernels.sched_score.ops import sched_score_argmax, sched_score_topb
 from repro.kernels.sched_score.ref import (
-    sched_compact_topb_ref,
     sched_score_argmax_ref,
     sched_score_topb_ref,
 )
@@ -288,25 +283,6 @@ class TestSchedScoreRoute:
         np.testing.assert_array_equal(np.asarray(ik), np.asarray(ir))
         np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
 
-    @given(seed=st.integers(0, 1000), b=st.sampled_from([1, 8, 32]),
-           density=st.floats(0.0, 1.0))
-    @settings(max_examples=15, deadline=None)
-    def test_compact_topb_matches_oracle(self, seed, b, density):
-        w = 256
-        ks = jax.random.split(jax.random.PRNGKey(seed + 7), 2)
-        req = jax.random.permutation(
-            ks[0], jnp.arange(w * 3, dtype=jnp.int32))[:w]
-        alive = jax.random.bernoulli(ks[1], density, (w,))
-        wait, cost, urg, _, route = self._features(w, seed, density)
-        ck, nk, ik, sk = sched_compact_topb(
-            req, alive, wait, cost, urg, self.W5, b, route, blk=128)
-        cr, nr, ir, sr = sched_compact_topb_ref(
-            req, alive, wait, cost, urg, self.W5, min(b, w), route)
-        assert int(nk) == int(nr)
-        np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
-        np.testing.assert_array_equal(np.asarray(ik), np.asarray(ir))
-        np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
-
     def test_route_none_matches_four_weight(self):
         """Omitting route with a (4,) weights vector is the pre-fleet
         path — it must stay byte-identical to passing route=None."""
@@ -332,118 +308,18 @@ class TestSchedScoreRoute:
         np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
 
 
-class TestCompactTopB:
-    """Fused compaction + score + top-B tick megakernel vs the two-pass
-    oracle (XLA cumsum-scatter, then `sched_score_topb` over the
-    compacted pool): exact equality on the compacted ids, the live
-    count, and the (idx, score) ranking — including first-occurrence
-    ties, the exhausted region, and an undersized (fully live) window."""
+class TestInterpretMode:
+    """Compiled on TPU, interpreted on CPU, and nothing silent elsewhere:
+    a mis-detected device must not quietly run the interpreter."""
 
-    W = jnp.asarray([1.0, 0.8, 0.5, 650.0], jnp.float32)
+    @pytest.mark.parametrize("backend,expect", [
+        ("cpu", True), ("tpu", False), ("gpu", RuntimeError)])
+    def test_backend_switch(self, monkeypatch, backend, expect):
+        from repro.kernels import interpret_mode
 
-    def _pool(self, w, seed, density=0.7):
-        ks = jax.random.split(jax.random.PRNGKey(seed), 5)
-        req = jax.random.permutation(
-            ks[0], jnp.arange(w * 3, dtype=jnp.int32))[:w]
-        alive = jax.random.bernoulli(ks[1], density, (w,))
-        wait = jax.random.uniform(ks[2], (w,)) * 5e3
-        cost = jax.random.uniform(ks[3], (w,)) * 3000 + 0.5
-        urg = jax.random.uniform(ks[4], (w,)) * 2
-        return req, alive, wait, cost, urg
-
-    def _check(self, w, b, seed=0, density=0.7, blk=128):
-        req, alive, wait, cost, urg = self._pool(w, seed, density)
-        ck, nk, ik, sk = sched_compact_topb(
-            req, alive, wait, cost, urg, self.W, b, blk=blk)
-        cr, nr, ir, sr = sched_compact_topb_ref(
-            req, alive, wait, cost, urg, self.W, min(b, w))
-        assert int(nk) == int(nr)
-        np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
-        np.testing.assert_array_equal(np.asarray(ik), np.asarray(ir))
-        np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
-
-    @given(seed=st.integers(0, 1000), w=st.sampled_from([128, 256, 512]),
-           b=st.sampled_from([1, 8, 32]), density=st.floats(0.0, 1.0))
-    @settings(max_examples=15, deadline=None)
-    def test_property_matches_two_pass(self, seed, w, b, density):
-        self._check(w, b, seed=seed, density=density)
-
-    def test_two_pass_kernel_parity(self):
-        """The fused kernel must agree with literally running the
-        existing two kernels back to back (compaction in XLA, ranking
-        via `sched_score_topb`) — the path it replaces."""
-        w, b = 512, 16
-        req, alive, wait, cost, urg = self._pool(w, seed=11)
-        ck, nk, ik, sk = sched_compact_topb(
-            req, alive, wait, cost, urg, self.W, b)
-        pos = jnp.cumsum(alive.astype(jnp.int32)) - 1
-        tgt = jnp.where(alive, pos, w)
-        cw = jnp.zeros((w,)).at[tgt].set(wait, mode="drop")
-        cc = jnp.ones((w,)).at[tgt].set(cost, mode="drop")
-        cu = jnp.zeros((w,)).at[tgt].set(urg, mode="drop")
-        mask = jnp.arange(w) < nk
-        i2, s2 = sched_score_topb(cw, cc, cu, mask, self.W, b)
-        live = min(int(nk), b)
-        np.testing.assert_array_equal(
-            np.asarray(ik)[:live], np.asarray(i2)[:live])
-        np.testing.assert_array_equal(
-            np.asarray(sk)[:live], np.asarray(s2)[:live])
-
-    def test_tie_breaking_first_occurrence(self):
-        """Duplicate feature rows tie exactly; ranking must resolve by
-        ascending compacted index (stable compaction keeps slot order,
-        so this is also ascending slot order)."""
-        w, half = 256, 128
-        req, alive, wait, cost, urg = self._pool(w, seed=9, density=1.0)
-        wait = wait.at[half:].set(wait[:half])
-        cost = cost.at[half:].set(cost[:half])
-        urg = urg.at[half:].set(urg[:half])
-        alive = jnp.ones((w,), bool).at[::7].set(False)  # shift positions
-        ck, nk, ik, sk = sched_compact_topb(
-            req, alive, wait, cost, urg, self.W, 32)
-        cr, nr, ir, sr = sched_compact_topb_ref(
-            req, alive, wait, cost, urg, self.W, 32)
-        np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
-        np.testing.assert_array_equal(np.asarray(ik), np.asarray(ir))
-        np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
-
-    def test_exhausted_region(self):
-        """b far above the live count: ranks >= n_live must yield the
-        (rank, NEG) sentinel rows exactly like top_k over the compacted
-        sentinel tail."""
-        self._check(128, 32, seed=5, density=0.05)
-        self._check(128, 16, seed=6, density=0.0)  # nothing alive
-
-    def test_undersized_window_fully_live(self):
-        """A fully live pool (the undersized-W overflow regime: every
-        slot occupied, the queue overflow waiting outside) compacts to
-        the identity and still ranks exactly."""
-        self._check(256, 16, seed=7, density=1.0)
-
-    def test_non_lane_aligned_width(self):
-        self._check(100, 8, seed=4, density=0.5)
-        self._check(7, 4, seed=8, density=0.6)
-
-    @pytest.mark.parametrize("w,blk", [(1024, 128), (4096, 256)])
-    def test_real_queue_depths(self, w, blk):
-        """The windowed engine's production capacities (window_for caps
-        at 4096).  On CPU this validates via interpret mode; on TPU the
-        same call compiles the kernel (interpret_mode() is False) —
-        the compiled non-interpret parity pass."""
-        self._check(w, 64, seed=3, density=0.6, blk=blk)
-
-    @pytest.mark.skipif(jax.default_backend() != "tpu",
-                        reason="compiled non-interpret pass needs a TPU")
-    @pytest.mark.parametrize("w", [1024, 4096])
-    def test_compiled_non_interpret_parity(self, w):
-        """Explicit compiled-mode parity at real queue depths: force
-        interpret=False regardless of backend detection."""
-        req, alive, wait, cost, urg = self._pool(w, seed=12, density=0.6)
-        ck, nk, ik, sk = sched_compact_topb(
-            req, alive, wait, cost, urg, self.W, 64, interpret=False)
-        cr, nr, ir, sr = sched_compact_topb_ref(
-            req, alive, wait, cost, urg, self.W, 64)
-        assert int(nk) == int(nr)
-        np.testing.assert_array_equal(np.asarray(ck), np.asarray(cr))
-        np.testing.assert_array_equal(np.asarray(ik), np.asarray(ir))
-        np.testing.assert_array_equal(np.asarray(sk), np.asarray(sr))
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if expect is RuntimeError:
+            with pytest.raises(RuntimeError, match="gpu"):
+                interpret_mode()
+        else:
+            assert interpret_mode() is expect

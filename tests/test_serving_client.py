@@ -406,6 +406,37 @@ class TestDonationSafety:
             with pytest.raises(RuntimeError):
                 np.asarray(leaf)  # any host materialization must fail
 
+    def test_transfer_buffers_untouched_until_pull(self):
+        """The tick is dispatched asynchronously and may read its NumPy
+        staging arguments in place (zero-copy on the CPU) until it ends.
+        The host must not refill them between the dispatch and the
+        blocking summary pull: doing so raced the device and made
+        decisions vary from run to run."""
+        sess = self._session()
+        for i in range(6):
+            sess.submit(Request(rid=i, prompt=None, max_new=25.0, p50=25.0,
+                                bucket=0))
+        tick, checks = sess._tick, []
+
+        class _Summary:  # checks the staged args when the host pulls
+            def __init__(self, summary, staged):
+                self.summary, self.staged = summary, staged
+
+            def __array__(self, dtype=None, copy=None):
+                checks.append(all(np.array_equal(buf, snap)
+                                  for buf, snap in self.staged))
+                return np.asarray(self.summary, dtype)
+
+        def spy(*args):
+            staged = [(a, a.copy()) for a in args if isinstance(a, np.ndarray)]
+            *out, summary = tick(*args)
+            return (*out, _Summary(summary, staged))
+
+        sess._tick = spy
+        out = sess.drain(max_polls=4000)
+        assert all(r.status == "completed" for r in out)
+        assert checks and all(checks)
+
     def test_post_drain_poll_is_transfer_free(self):
         """After drain() the pool is empty and the epoch is a fixpoint:
         poll() must replay the cached result without touching the

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get, get_smoke
+from repro.launch.mesh import make_host_mesh
 from repro.models import init_caches, init_model
 from repro.models.model import cache_axes, lm_loss
 from repro.sharding.rules import DEFAULT_ACT_RULES, constrain, spec_for
@@ -19,7 +20,7 @@ class TestCacheSharding:
     (cache_batch -> data, cache_seq -> model), never silently replicate."""
 
     def test_kv_cache_spec_shards_batch_and_seq(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh()
         axes = ("layers", "cache_batch", "cache_seq", "kv_heads", None)
         spec = spec_for(axes, (64, 128, 32768, 40, 128), mesh,
                         DEFAULT_ACT_RULES)
@@ -31,7 +32,7 @@ class TestCacheSharding:
     def test_launch_cache_shardings_not_replicated(self):
         from repro.launch.specs import _abstract_caches, _cache_shardings
         cfg = get("qwen1.5-32b")
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh()
         sds = _abstract_caches(cfg, 128, 32768)
         sh = _cache_shardings(cfg, sds, mesh)
         spec = sh["kv"].k.spec
@@ -87,7 +88,7 @@ class TestConstrain:
         assert y is x or np.array_equal(np.asarray(y), np.asarray(x))
 
     def test_applies_inside_mesh(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh()
 
         def f(x):
             return constrain(x, "batch", None) * 2

@@ -12,6 +12,7 @@ from hypothesis import given, settings, strategies as st
 from repro.config import ServeConfig, TrainConfig
 from repro.configs import get_smoke
 from repro.data import DataConfig, make_batches
+from repro.launch.mesh import make_host_mesh
 from repro.checkpoint import latest_step, restore_checkpoint, save_checkpoint
 from repro.models import init_model
 from repro.serving import generate
@@ -176,7 +177,7 @@ class TestShardingRules:
         os.environ.setdefault("XLA_FLAGS", "")
         from repro.sharding.rules import spec_for
         from jax.sharding import PartitionSpec as P
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh()
         # heads=14 not divisible by model=1? (1 divides everything) -> kept
         assert spec_for(("embed", "heads"), (896, 14), mesh) == P(("data",), "model")
 
@@ -185,7 +186,7 @@ class TestShardingRules:
     @settings(max_examples=12, deadline=None)
     def test_property_never_invalid(self, dim, axis):
         from repro.sharding.rules import spec_for
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_host_mesh()
         spec = spec_for((axis,), (dim,), mesh)
         size = 1  # all axes size 1 in this mesh
         assert dim % size == 0  # trivially consistent; exercised on 512-dev
