@@ -16,12 +16,10 @@ per-request rate must stay within 2x of N=1e3).
 completion over the mock, and the deprecated ScheduledClient shim must
 still run a closed list end to end.
 
-`--profile` runs the same sweep with the session's per-poll wall-time
-accounting on and prints the stage/dispatch/pull/grants breakdown per
-poll — the fastest way to see whether a regression is host-side
-(staging, mirrors), dispatch overhead, or device compute (the blocking
-summary pull).  Pass `--trace-dir DIR` to also capture a
-`jax.profiler` trace of the N=1e3 run for TensorBoard/Perfetto.
+The per-poll breakdown of the live path, on the chip and on the device
+trace's clock, is the chip benchmark's traced run:
+`python3 bench/run.py --workload frontend.high_congestion --seed <n>
+--seconds <s> --trace 1`.
 """
 from __future__ import annotations
 
@@ -82,17 +80,12 @@ def _requests(n: int) -> list[Request]:
 
 
 def client_session_bench(n_requests: int, window: int = WINDOW,
-                         grants: int = GRANTS, profile: bool = False,
-                         trace_dir: str | None = None,
-                         repeats: int = 3) -> dict:
+                         grants: int = GRANTS, repeats: int = 3) -> dict:
     # Single-drain wall time swings ~1.5x run to run on a busy host, which
     # is wider than the check_regression tolerance band — report the best
     # of `repeats` full drains so both the committed rows and the in-gate
     # measurement see the machine's actual capability, not its worst
-    # scheduling hiccup.  Profiling/tracing runs stay single-drain so the
-    # accumulated per-poll breakdown covers exactly one drain.
-    if profile or trace_dir:
-        repeats = 1
+    # scheduling hiccup.
     policy = _bench_policy()
     phys = _fast_physics()
     best = None
@@ -101,28 +94,12 @@ def client_session_bench(n_requests: int, window: int = WINDOW,
             MockProvider(phys, dt_ms=25.0), policy,
             SessionConfig(window=window, max_grants=grants, dt_ms=25.0),
             clock="virtual", phys=phys)
-        prof = sess.enable_profiling() if profile else None
         for r in _requests(n_requests):
             sess.submit(r)
         max_polls = 20 * (n_requests // grants + 50)
-        if trace_dir:
-            import jax
-            jax.profiler.start_trace(trace_dir)
         t0 = time.perf_counter()
         sess.drain(max_polls=max_polls)
         wall = time.perf_counter() - t0
-        if trace_dir:
-            import jax
-            jax.profiler.stop_trace()
-        if prof and prof["polls"]:
-            np_ = prof["polls"]
-            acct = sum(
-                prof[k] for k in ("stage", "dispatch", "pull", "grants"))
-            print(f"    profile N={n_requests} ({np_} device polls, "
-                  f"{acct / np_ * 1e6:7.1f}us/poll accounted):")
-            for k in ("stage", "dispatch", "pull", "grants"):
-                print(f"      {k:9s} {prof[k] / np_ * 1e6:8.1f}us/poll "
-                      f"({prof[k] / acct * 100:5.1f}%)")
         n_done = sess.stats.n_completed
         if n_done != n_requests:
             raise RuntimeError(
@@ -217,14 +194,4 @@ def smoke() -> int:
 if __name__ == "__main__":
     if "--smoke" in sys.argv:
         sys.exit(smoke())
-    if "--profile" in sys.argv:
-        trace_dir = None
-        if "--trace-dir" in sys.argv:
-            trace_dir = sys.argv[sys.argv.index("--trace-dir") + 1]
-        for i, n in enumerate(N_SWEEP):
-            # trace only the first (small) run: a 1e5-poll trace is
-            # gigabytes and the per-poll program is identical
-            client_session_bench(n, profile=True,
-                                 trace_dir=trace_dir if i == 0 else None)
-        sys.exit(0)
     write_client_bench()

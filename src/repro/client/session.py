@@ -81,6 +81,7 @@ from repro.client.provider import (
 from repro.client.request import Request
 from repro.client.resilience import ResilienceConfig, Watchdog
 from repro.core import overload as olc
+from repro.core import stages
 from repro.core.policy import ALLOC_ADRR, PolicyConfig, n_classes
 from repro.core.scheduler import IDLE, charge_resubmit, schedule_batch
 from repro.core.types import (
@@ -154,6 +155,48 @@ class SessionStats:
 RetryPolicy = Callable[[float, int], float]
 
 
+# the phases of a profiled poll, in order, and the `enable_profiling()`
+# bucket each adds to
+_PHASE_BUCKET = {"ingest": "stage", "classify": "stage", "staging": "stage",
+                 "dispatch": "dispatch", "mirrors": "stage", "pull": "pull",
+                 "grants": "grants"}
+
+
+class _PollSpans:
+    """The phases of profiled polls.  Each phase is a profiler annotation
+    `session.<phase>` (so a device trace shows it on the device ops'
+    clock) and a `perf_counter` duration added to its bucket of `prof`.
+    Phases are contiguous: `to(phase)` ends the open phase and starts the
+    next, `end()` ends the last one.  The annotations are swapped back to
+    back and the bookkeeping runs inside them, so a trace shows no gap
+    between phases."""
+
+    def __init__(self, prof: dict):
+        self.prof = prof
+        self._phase: Optional[str] = None
+        self._start = 0.0
+        self._annotation = None
+
+    def to(self, phase: str) -> None:
+        annotation = jax.profiler.TraceAnnotation("session." + phase)
+        now = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(None, None, None)
+        annotation.__enter__()
+        self._book(now)
+        self._phase, self._start, self._annotation = phase, now, annotation
+
+    def end(self) -> None:
+        self._book(time.perf_counter())
+        self.prof["polls"] += 1
+        self._annotation.__exit__(None, None, None)
+        self._phase = self._annotation = None
+
+    def _book(self, now: float) -> None:
+        if self._phase is not None:
+            self.prof[_PHASE_BUCKET[self._phase]] += now - self._start
+
+
 # ---------------------------------------------------------------------------
 # The fused device tick (module-level so compilations are shared)
 # ---------------------------------------------------------------------------
@@ -166,6 +209,7 @@ _ST_ARRIVAL, _ST_BUCKET, _ST_CLS, _ST_TOKENS = 0, 1, 2, 3
 _ST_P50, _ST_P90, _ST_DEADLINE = 4, 5, 6
 
 
+@stages.scoped(stages.ADMIT)
 def _compact_and_admit(batch: RequestBatch, req, alive, staged, n_stage):
     """Stable-compact live slots to the prefix (preserving request-id
     order — the ordering layer's tie-break invariant) and append up to
@@ -215,6 +259,7 @@ def _compact_and_admit(batch: RequestBatch, req, alive, staged, n_stage):
     return new_batch, new_req, n_live + n_stage
 
 
+@stages.scoped(stages.APPLY)
 def _apply_body(policy: PolicyConfig, batch: RequestBatch,
                 state: SimState, d, accepted, delay_ms):
     """Post-dispatch state transition on the (W,) pool — the live-path
@@ -447,7 +492,7 @@ class ClientSession:
         self.phys = phys if phys is not None else default_physics()
         self.retry_policy = retry_policy or honor_retry_after
         self.stats = SessionStats()
-        self._prof: Optional[dict] = None
+        self._spans: Optional[_PollSpans] = None
 
         w = cfg.window
         self._k = n_classes(policy)
@@ -593,10 +638,14 @@ class ClientSession:
         waiting on the device, `grants` — the provider submit loop and
         verdict bookkeeping.  `polls` counts profiled epochs (the
         post-drain idle fast path is excluded — it does no device
-        work)."""
-        self._prof = {"stage": 0.0, "dispatch": 0.0, "pull": 0.0,
-                      "grants": 0.0, "polls": 0}
-        return self._prof
+        work).  Each phase of a profiled poll is also a profiler
+        annotation `session.<phase>` (ingest, classify, staging,
+        dispatch, mirrors, pull, grants; the first three and mirrors
+        make up `stage`), seen by a running `jax.profiler` trace."""
+        prof = {"stage": 0.0, "dispatch": 0.0, "pull": 0.0,
+                "grants": 0.0, "polls": 0}
+        self._spans = _PollSpans(prof)
+        return prof
 
     def requests(self) -> list[Request]:
         return list(self._reqs)
@@ -697,9 +746,9 @@ class ClientSession:
                 and not self._tickets and not self._unfinished):
             return self._idle_cache._replace(now_ms=now_ms)
 
-        prof = self._prof
-        if prof is not None:
-            _tp0 = time.perf_counter()
+        spans = self._spans
+        if spans is not None:
+            spans.to("ingest")
         now32 = np.float32(now_ms)
         nl = self._n_live
 
@@ -749,6 +798,8 @@ class ClientSession:
                 self._comp[1, :ncomp] = fins
                 self._slot_finish[slots] = fins
 
+        if spans is not None:
+            spans.to("classify")
         # 2. retirement classification on the f32 mirrors — the same
         # comparison chains `_complete_and_timeout` runs on the device
         # (sub/mul/compare round identically in f32; no FMA can form
@@ -790,16 +841,18 @@ class ClientSession:
         n_alive = int(alive.sum())
 
         # 3. stage arrivals + 4. the fused device step
+        if spans is not None:
+            spans.to("staging")
         staged_rids = self._stage_admissions(now_ms, w - n_alive)
         n_stage = len(staged_rids)
-        if prof is not None:
-            _tp1 = time.perf_counter()
+        if spans is not None:
+            spans.to("dispatch")
         extra = (self._resub_charge,) if self._watchdog is not None else ()
         self._win_batch, self._dev_state, d, summary = self._tick(
             self._win_batch, self._dev_state, self._pending,
             self._comp, self._staged_px, np.int32(n_stage), now32, *extra)
-        if prof is not None:
-            _tp2 = time.perf_counter()
+        if spans is not None:
+            spans.to("mirrors")
         # the dispatch is async: the mirror bookkeeping below depends
         # only on host state, so it runs while the device executes —
         # the blocking summary pull comes after
@@ -828,11 +881,11 @@ class ClientSession:
         self._n_live = nt
 
         # 6. submit grants (decision order); collect 429 verdicts
-        if prof is not None:
-            _tp3 = time.perf_counter()
+        if spans is not None:
+            spans.to("pull")
         summary = np.asarray(summary)  # the one device->host pull
-        if prof is not None:
-            _tp4 = time.perf_counter()
+        if spans is not None:
+            spans.to("grants")
         # the tick has ended, so its transfer buffers may be reset now
         if ncomp:
             self._comp[0, :ncomp] = w
@@ -907,13 +960,8 @@ class ClientSession:
             hint = min(hint, float((now32 + ad[b:][bounced]).min()))
         self._defer_hint = hint
 
-        if prof is not None:
-            _tp5 = time.perf_counter()
-            prof["stage"] += (_tp1 - _tp0) + (_tp3 - _tp2)
-            prof["dispatch"] += _tp2 - _tp1
-            prof["pull"] += _tp4 - _tp3
-            prof["grants"] += _tp5 - _tp4
-            prof["polls"] += 1
+        if spans is not None:
+            spans.end()
         progressed = bool(
             completed or abandoned or rejected or admitted or deferred
             or throttled or staged_rids)

@@ -44,7 +44,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.core import drr, ordering, overload
+from repro.core import drr, ordering, overload, stages
 from repro.core.policy import ALLOC_ADRR, PolicyConfig, n_classes
 from repro.core.types import INFLIGHT, RequestBatch, SimState
 
@@ -244,35 +244,39 @@ def schedule_batch(
     k = n_classes(cfg)
     bmax = min(int(max_grants), batch.n)
     now = state.now_ms
-    elig = ordering.eligibility(
-        batch, state.req.status, state.req.defer_until, now
-    )
-    eff_cls = effective_class(cfg, batch)
-    cls_onehot = eff_cls[None, :] == jnp.arange(k, dtype=jnp.int32)[:, None]
-    elig_kn = cls_onehot & elig[None, :]
+    with jax.named_scope(stages.ORDER):
+        elig = ordering.eligibility(
+            batch, state.req.status, state.req.defer_until, now
+        )
+        eff_cls = effective_class(cfg, batch)
+        cls_onehot = (eff_cls[None, :]
+                      == jnp.arange(k, dtype=jnp.int32)[:, None])
+        elig_kn = cls_onehot & elig[None, :]
 
-    # --- layer 2 once: ranked candidates per class + global FIFO lane
-    rank_idx, n_elig_cls = ordering.select_top_b(
-        batch, elig_kn, now, cfg, bmax, backend=backend, route=route
-    )
-    glob_idx, n_elig_tot = ordering.rank_fifo(batch, elig, bmax,
-                                              backend=backend)
-    # grantable candidates this batch can actually see per lane
-    visible_cls = jnp.minimum(n_elig_cls, bmax)
-    visible_glob = jnp.minimum(n_elig_tot, bmax)
+        # --- layer 2 once: ranked candidates per class + global FIFO lane
+        rank_idx, n_elig_cls = ordering.select_top_b(
+            batch, elig_kn, now, cfg, bmax, backend=backend, route=route
+        )
+        glob_idx, n_elig_tot = ordering.rank_fifo(batch, elig, bmax,
+                                                  backend=backend)
+        # grantable candidates this batch can actually see per lane
+        visible_cls = jnp.minimum(n_elig_cls, bmax)
+        visible_glob = jnp.minimum(n_elig_tot, bmax)
 
-    inflight_mask = state.req.status == INFLIGHT
-    inflight_cls0 = (cls_onehot & inflight_mask[None, :]).sum(axis=1).astype(
-        jnp.int32
-    )
+    with jax.named_scope(stages.GRANT):
+        # the grant loop's starting per-class inflight counts
+        inflight_mask = state.req.status == INFLIGHT
+        inflight_cls0 = (cls_onehot & inflight_mask[None, :]).sum(
+            axis=1).astype(jnp.int32)
 
     # --- layer 3 once: a single severity drives all B ladder decisions
-    sev = overload.severity_score(
-        cfg,
-        inflight_total=state.provider.inflight,
-        n_pending=n_elig_tot,
-        ema_latency_ratio=state.sched.ema_latency_ratio,
-    )
+    with jax.named_scope(stages.OVERLOAD):
+        sev = overload.severity_score(
+            cfg,
+            inflight_total=state.provider.inflight,
+            n_pending=n_elig_tot,
+            ema_latency_ratio=state.sched.ema_latency_ratio,
+        )
 
     def grant(g, carry):
         (deficit, rr_turn, infl_cls, infl_tot, cls_ptr, glob_ptr,
@@ -339,20 +343,20 @@ def schedule_batch(
             infl_at.at[g].set(infl_tot),
         )
 
-    carry0 = (
-        state.sched.deficit,
-        state.sched.rr_turn,
-        inflight_cls0,
-        state.provider.inflight,
-        jnp.zeros((k,), jnp.int32),
-        jnp.zeros((), jnp.int32),
-        jnp.full((bmax,), IDLE, jnp.int32),
-        jnp.zeros((bmax,), jnp.int32),
-        jnp.zeros((bmax,), jnp.int32),
-    )
-    (deficit, rr_turn, _, _, _, _, actions, idxs, infl_at) = jax.lax.fori_loop(
-        0, bmax, grant, carry0
-    )
+    with jax.named_scope(stages.GRANT):
+        carry0 = (
+            state.sched.deficit,
+            state.sched.rr_turn,
+            inflight_cls0,
+            state.provider.inflight,
+            jnp.zeros((k,), jnp.int32),
+            jnp.zeros((), jnp.int32),
+            jnp.full((bmax,), IDLE, jnp.int32),
+            jnp.zeros((bmax,), jnp.int32),
+            jnp.zeros((bmax,), jnp.int32),
+        )
+        (deficit, rr_turn, _, _, _, _, actions, idxs,
+         infl_at) = jax.lax.fori_loop(0, bmax, grant, carry0)
     provider_idx = None
     if endpoint is not None:
         # gather-only: the endpoint choice was fixed before allocation,

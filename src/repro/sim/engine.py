@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import overload as olc
+from repro.core import stages
 from repro.core.numerics import pinned
 from repro.core.policy import ALLOC_ADRR, PolicyConfig, n_classes
 from repro.core.scheduler import BatchDecision, schedule_batch
@@ -148,6 +149,7 @@ def _completed_ratio_sum(
     return ratio.sum(), k  # reprolint: disable=RPL001
 
 
+@stages.scoped(stages.RETIRE)
 def _complete_and_timeout(
     cfg: PolicyConfig,
     phys: ProviderPhysics,
@@ -264,6 +266,7 @@ def _complete_and_timeout(
     )
 
 
+@stages.scoped(stages.APPLY)
 def _apply_batch(
     cfg: PolicyConfig,
     phys: ProviderPhysics,
@@ -516,6 +519,7 @@ def _window_view(
     return win_batch, win_req, occ
 
 
+@stages.scoped(stages.RETIRE)
 def _retire_window(
     cfg: PolicyConfig,
     phys: ProviderPhysics,
@@ -567,6 +571,7 @@ def _retire_window(
     return state, alive
 
 
+@stages.scoped(stages.ADMIT)
 def _compact_and_admit(
     batch: RequestBatch, win: WindowCarry, alive: jnp.ndarray, now
 ) -> WindowCarry:
@@ -675,7 +680,10 @@ def sim_tick(
             )
         )
     if windowed:
-        win_batch, win_req, _ = _window_view(batch, state.req, win.slot_req)
+        # the admitted window's view is the admission stage's output
+        with jax.named_scope(stages.ADMIT):
+            win_batch, win_req, _ = _window_view(batch, state.req,
+                                                 win.slot_req)
         d_batch, d_state = win_batch, state._replace(req=win_req)
     else:
         d_batch, d_state = batch, state
@@ -683,12 +691,13 @@ def sim_tick(
     if fleet is not None:
         p = fleet.phys.base_ms.shape[0]
         if p > 1:
-            endpoint, route = route_requests(
-                fleet.phys, state.fleet, d_batch.p50,
-                comfort_t=comfort_t, avail_t=avail_t,
-                retry_after_ms=fl_dyn.retry_after_ms
-                if has_fleet_limiter else None,
-            )
+            with jax.named_scope(stages.ROUTE):
+                endpoint, route = route_requests(
+                    fleet.phys, state.fleet, d_batch.p50,
+                    comfort_t=comfort_t, avail_t=avail_t,
+                    retry_after_ms=fl_dyn.retry_after_ms
+                    if has_fleet_limiter else None,
+                )
         else:
             # static P == 1: no routing choice exists — endpoint is an
             # integer constant and route stays None, so the scored
@@ -707,8 +716,9 @@ def sim_tick(
         # drop path (IDLE rows never carry a release anyway).
         # d.provider_idx is already endpoint-valued — no translation.
         w = win.slot_req.shape[0]
-        d = d._replace(
-            req_idx=win.slot_req[jnp.clip(d.req_idx, 0, w - 1)])
+        with jax.named_scope(stages.APPLY):
+            d = d._replace(
+                req_idx=win.slot_req[jnp.clip(d.req_idx, 0, w - 1)])
     state = _apply_batch(
         policy, phys, batch, jitter, state, d,
         comfort_scale=comfort_t,
