@@ -226,10 +226,11 @@ def windowed_dispatch_bench(b: int, n_req: int, w: int,
     """
     assert w <= n_req
     policy, batch, _, state, win = _full_window(n_req, w)
+    table = eng.pack_batch(batch)  # once, outside the step, as run_sim does
 
     @functools.partial(jax.jit, static_argnames=("max_grants",))
     def step(state, win, max_grants):
-        wb, wr, _ = eng._window_view(batch, state.req, win.slot_req)
+        wb, wr, _ = eng._window_view(batch, state.req, win.slot_req, table)
         d = schedule_batch(policy, wb, state._replace(req=wr),
                            max_grants=max_grants)
         return d._replace(req_idx=win.slot_req[jnp.clip(d.req_idx, 0, w - 1)])

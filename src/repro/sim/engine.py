@@ -486,27 +486,41 @@ def _apply_batch(
     )
 
 
+def pack_batch(batch: RequestBatch) -> jnp.ndarray:
+    """The batch's static fields as one `(8, N)` int32 table, one row per
+    field in `RequestBatch` order: floats and ints bitcast, `valid` as
+    0/1.  A gather of its columns moves the fields' bits unchanged, so a
+    view built from the table is bit-identical to one gathered field by
+    field.  Field-major, the gathered `(8, W)` block is lane-dense on the
+    TPU and each field is a row of it; an `(N, 8)` table left `(W, 1)`
+    pieces that cost a relayout each."""
+    return jnp.stack(
+        [f.astype(jnp.int32) if f.dtype == jnp.bool_
+         else jax.lax.bitcast_convert_type(f, jnp.int32) for f in batch])
+
+
 def _window_view(
-    batch: RequestBatch, req: RequestState, slot_req: jnp.ndarray
+    batch: RequestBatch, req: RequestState, slot_req: jnp.ndarray,
+    batch_table: jnp.ndarray | None = None,
 ) -> tuple[RequestBatch, RequestState, jnp.ndarray]:
     """Gather the window's (W,)-shaped view of the batch and request
     state.  Empty slots (sentinel id n) clamp their gathers to a real
     row but are neutralized: valid=False (never eligible), terminal
-    status (never counted live), finish=inf (never landing).  Returns
-    (win_batch, win_req, occupied)."""
+    status (never counted live), finish=inf (never landing).  The batch
+    fields come from one gather of `batch_table` (`pack_batch`, packed
+    here when not given); the request state changes every tick, so its
+    fields keep a gather each.  Returns (win_batch, win_req, occupied)."""
     n = batch.n
     occ = slot_req < n
     safe = jnp.minimum(slot_req, n - 1)
-    win_batch = RequestBatch(
-        arrival_ms=batch.arrival_ms[safe],
-        bucket=batch.bucket[safe],
-        cls=batch.cls[safe],
-        true_tokens=batch.true_tokens[safe],
-        p50=batch.p50[safe],
-        p90=batch.p90[safe],
-        deadline_budget_ms=batch.deadline_budget_ms[safe],
-        valid=batch.valid[safe] & occ,
-    )
+    if batch_table is None:
+        batch_table = pack_batch(batch)
+    cols = batch_table[:, safe]
+    win_batch = RequestBatch(*(
+        cols[j] != 0 if f.dtype == jnp.bool_
+        else jax.lax.bitcast_convert_type(cols[j], f.dtype)
+        for j, f in enumerate(batch)))
+    win_batch = win_batch._replace(valid=win_batch.valid & occ)
     win_req = RequestState(
         status=jnp.where(occ, req.status[safe], jnp.int32(REJECTED)),
         submit_ms=req.submit_ms[safe],
@@ -528,6 +542,7 @@ def _retire_window(
     win: WindowCarry,
     avail_t=None,
     retry_after_ms=None,
+    batch_table: jnp.ndarray | None = None,
 ) -> tuple[SimState, jnp.ndarray]:
     """Windowed completion/timeout/stale pass: run the *dense* transition
     on the (W,) window view — one code path, so the formulas cannot
@@ -536,9 +551,11 @@ def _retire_window(
     because `_completed_ratio_sum` reduces a canonical fixed-width
     buffer in request-id order (the window's compaction invariant).
     Returns (state, alive) where alive marks slots still live (PENDING
-    or INFLIGHT) after retirement."""
+    or INFLIGHT) after retirement.  `batch_table` is the batch's packed
+    table (`pack_batch`), packed here when not given."""
     n = batch.n
-    win_batch, win_req, occ = _window_view(batch, state.req, win.slot_req)
+    win_batch, win_req, occ = _window_view(batch, state.req, win.slot_req,
+                                           batch_table)
     win_state = state._replace(req=win_req)
     win_state = _complete_and_timeout(cfg, phys, win_batch, win_state,
                                       avail_t=avail_t,
@@ -623,6 +640,7 @@ def sim_tick(
     dynamics: ProviderDynamics | None = None,
     fleet: Fleet | None = None,
     collect_decisions: bool = False,
+    batch_table: jnp.ndarray | None = None,
 ):
     """One decision epoch of the engine as a single traceable body:
 
@@ -641,7 +659,9 @@ def sim_tick(
     bucket grid, and `routing.route_requests` fixes each request's
     endpoint (and route score term) before dispatch.  At the static
     P == 1 the route term is absent and the tick is the exact
-    single-provider program.  Returns (state, win, ys) with ys the
+    single-provider program.  `batch_table` is the batch's packed
+    table (`pack_batch`) for the window views; `run_sim` packs it once
+    per call, outside the scan.  Returns (state, win, ys) with ys the
     per-tick decision trace row (or None).
     """
     windowed = win is not None
@@ -655,7 +675,8 @@ def sim_tick(
     if windowed:
         state, alive = _retire_window(policy, phys, batch, state, win,
                                       avail_t=avail_t,
-                                      retry_after_ms=retry_ms)
+                                      retry_after_ms=retry_ms,
+                                      batch_table=batch_table)
         win = _compact_and_admit(batch, win, alive, now)
     else:
         state = _complete_and_timeout(policy, phys, batch, state,
@@ -683,7 +704,7 @@ def sim_tick(
         # the admitted window's view is the admission stage's output
         with jax.named_scope(stages.ADMIT):
             win_batch, win_req, _ = _window_view(batch, state.req,
-                                                 win.slot_req)
+                                                 win.slot_req, batch_table)
         d_batch, d_state = win_batch, state._replace(req=win_req)
     else:
         d_batch, d_state = batch, state
@@ -804,9 +825,12 @@ def run_sim(
             dynamics=dynamics,
             fleet=fleet,
             collect_decisions=collect_decisions,
+            batch_table=batch_table,
         )
         return (state, win), ys
 
+    # the batch never changes inside the scan: pack it once a call
+    batch_table = pack_batch(batch) if windowed else None
     win0 = init_window_carry(sim_cfg.window, n) if windowed else None
     xs = (
         jnp.arange(sim_cfg.n_ticks),
@@ -828,7 +852,8 @@ def run_sim(
         # admitted (arrived past the horizon, or overflow still queued)
         # with the one and only definition of the timeout rule.  O(N),
         # but once per run, not per tick.
-        final, _ = _retire_window(policy, phys, batch, final, win)
+        final, _ = _retire_window(policy, phys, batch, final, win,
+                                  batch_table=batch_table)
     final = _complete_and_timeout(policy, phys, batch, final)
     if collect_decisions:
         return final, trace

@@ -12,8 +12,16 @@ pins.
 Also covered: the overflow regime (W smaller than the live queue) must
 degrade gracefully — FIFO admission, no lost or duplicated requests —
 and the compacted window invariants (occupied prefix, request-id
-sorted) must hold tick over tick.
+sorted) must hold tick over tick.  The window view gathers the batch's
+static fields from one packed table: it must equal the view gathered
+field by field bit for bit, and the compiled scan must hold one packed
+gather per view in place of a gather per batch field.
 """
+import json
+import math
+import os
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,11 +36,16 @@ from repro.core.types import (
     INFLIGHT,
     PENDING,
     REJECTED,
+    RequestBatch,
+    RequestState,
     init_sim_state,
     init_window_carry,
 )
 from repro.sim import SimConfig, WorkloadConfig, default_physics, generate, run_sim
+from repro.sim import runner
 from repro.sim import scenarios as scn
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 REQ_FIELDS = ("status", "submit_ms", "finish_ms", "defer_until",
               "n_defers", "n_throttles")
@@ -286,3 +299,138 @@ class TestRunnerThreading:
             b = np.asarray(getattr(m_win, name))
             np.testing.assert_array_equal(a[np.isfinite(a)], b[np.isfinite(b)],
                                           err_msg=name)
+
+
+def _per_field_view(batch, req, slot_req):
+    """The window view gathered one field at a time: the reference the
+    packed gather must reproduce bit for bit."""
+    n = batch.n
+    occ = slot_req < n
+    safe = jnp.minimum(slot_req, n - 1)
+    wb = RequestBatch(*(f[safe] for f in batch))
+    wb = wb._replace(valid=wb.valid & occ)
+    wr = RequestState(
+        status=jnp.where(occ, req.status[safe], jnp.int32(REJECTED)),
+        submit_ms=req.submit_ms[safe],
+        finish_ms=jnp.where(occ, req.finish_ms[safe], jnp.inf),
+        defer_until=req.defer_until[safe],
+        n_defers=req.n_defers[safe],
+        n_throttles=req.n_throttles[safe],
+        endpoint=None if req.endpoint is None else req.endpoint[safe],
+    )
+    return wb, wr, occ
+
+
+def _raw(x):
+    """The array's bits: floats as their int32 view."""
+    a = np.asarray(x)
+    return a.view(np.int32) if a.dtype == np.float32 else a
+
+
+class TestPackedWindowView:
+    N, W = 40, 32
+
+    def _case(self, n_live=None, inf_finish=False, odd_floats=False,
+              padded=False, fleet_p=None):
+        n, w = self.N, self.W
+        rng = np.random.default_rng(8)
+        batch, _ = generate(jax.random.PRNGKey(8), WorkloadConfig(
+            n_requests=n, mix="heavy", congestion="high"))
+        valid = np.ones(n, bool)
+        if padded:
+            valid[n // 2:] = False
+        batch = batch._replace(valid=jnp.asarray(valid))
+        n_live = w if n_live is None else n_live
+        ids = np.sort(rng.choice(n, n_live, replace=False))
+        if odd_floats:  # in rows the window holds
+            tiny = np.float32(1e-40)  # subnormal: a move must keep it
+            batch = batch._replace(
+                arrival_ms=batch.arrival_ms.at[ids[1]].set(-0.0),
+                p50=batch.p50.at[ids[2]].set(tiny),
+                deadline_budget_ms=batch.deadline_budget_ms.at[ids[3]].set(
+                    -tiny))
+        finish = rng.uniform(0, 5e4, n).astype(np.float32)
+        if inf_finish:
+            finish[::3] = np.inf
+        req = RequestState(
+            status=jnp.asarray(rng.integers(0, 5, n), jnp.int32),
+            submit_ms=jnp.asarray(rng.uniform(0, 5e4, n), jnp.float32),
+            finish_ms=jnp.asarray(finish),
+            defer_until=jnp.asarray(rng.uniform(0, 5e4, n), jnp.float32),
+            n_defers=jnp.asarray(rng.integers(0, 3, n), jnp.int32),
+            n_throttles=jnp.asarray(rng.integers(0, 3, n), jnp.int32),
+            endpoint=None if fleet_p is None else jnp.asarray(
+                rng.integers(0, fleet_p, n), jnp.int32),
+        )
+        slot_req = np.full(w, n, np.int32)
+        slot_req[:n_live] = ids
+        return batch, req, jnp.asarray(slot_req)
+
+    @pytest.mark.parametrize("case", [
+        dict(n_live=20),                  # live window, empty sentinel slots
+        dict(inf_finish=True),
+        dict(odd_floats=True, n_live=20),  # -0.0 and subnormals
+        dict(),                           # all valid, full window
+        dict(padded=True),                # padding rows inside the window
+        dict(fleet_p=16, n_live=20),      # fleet state with endpoints
+    ], ids=["sentinel_slots", "inf_finish", "neg_zero_subnormal",
+            "all_valid", "padded_valid", "fleet_endpoint_p16"])
+    def test_packed_view_equals_per_field_view(self, case):
+        batch, req, slot_req = self._case(**case)
+        want = jax.jit(_per_field_view)(batch, req, slot_req)
+        for table in (None, eng.pack_batch(batch)):
+            got = jax.jit(eng._window_view)(batch, req, slot_req, table)
+            for part_w, part_g in zip(want, got):
+                if isinstance(part_w, tuple):
+                    assert part_g._fields == part_w._fields
+                    for name, a, b in zip(part_w._fields, part_w, part_g):
+                        assert (a is None) == (b is None), name
+                        if a is not None:
+                            assert np.asarray(a).dtype == np.asarray(b).dtype
+                            assert np.array_equal(_raw(a), _raw(b)), name
+                else:
+                    assert np.array_equal(_raw(part_w), _raw(part_g))
+        if case.get("odd_floats"):  # the odd values reached the window
+            assert (_raw(want[0].arrival_ms)
+                    == np.float32(-0.0).view(np.int32)).any()
+            assert (_raw(want[0].p50) == np.float32(1e-40).view(np.int32)).any()
+
+
+def test_scan_gathers_the_batch_once_per_window_view():
+    """The `sim.high_congestion` program (its configuration and traffic
+    under bench/, cut to a few ticks), compiled on the CPU: inside the
+    scan, each window view (retire's and admission's) gathers the batch
+    with one gather of `len(RequestBatch._fields)` values per slot; the
+    only other window-width gathers from the per-request arrays are those
+    of the request-state fields the stages read, one each."""
+    with open(os.path.join(ROOT, "bench/configs/sim_paper_n160.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(ROOT, "bench/traffic/high_congestion.json")) as f:
+        tr = json.load(f)
+    s, w = int(cfg["seeds_per_call"]), int(cfg["window"])
+    sim_cfg = SimConfig(dt_ms=float(cfg["dt_ms"]), n_ticks=4,
+                        k_slots=int(cfg["k_slots"]),
+                        ordering_backend=cfg["backend"], window=w)
+    keys = jax.vmap(jax.random.PRNGKey)(jnp.arange(0, s))
+    # the policy's values are operands: they do not shape the program
+    text = runner._run_scenario_seeds.lower(
+        strategy("final_adrr_olc"), default_physics(**cfg["provider"]),
+        keys, scn.get_scenario(tr["scenario"]), sim_cfg,
+        int(cfg["n_requests"]), tr["class_map"], tr["information"],
+        1.0).compile().as_text()
+    n_fields = len(RequestBatch._fields)
+    found = {"tick.retire": [], "tick.admit": []}
+    for m in re.finditer(
+            r"= \w+\[(\d+)[^\]]*\]\S* gather\(.*?slice_sizes=\{([\d,]+)\}"
+            r'.*?op_name="[^"]*/while/body/[^"]*?(tick\.retire|tick\.admit)'
+            r'/gather"', text):
+        width, sizes, stage = int(m[1]), m[2].split(","), m[3]
+        # from the (seeds, N) arrays and the (seeds, 8, N) table into the
+        # seeds x W window; values moved per slot = the slice's size
+        if width == s * w and len(sizes) >= 2:
+            found[stage].append(math.prod(int(x) for x in sizes))
+    # status and finish (retire); status, defer_until, n_defers (admit)
+    req_read = {"tick.retire": 2, "tick.admit": 3}
+    for stage, sizes in found.items():
+        assert sorted(sizes) == [1] * req_read[stage] + [n_fields], (
+            stage, sizes)
