@@ -186,8 +186,7 @@ def live_phase(backend: str, logs: dict, n: int = LIVE_N, w: int = LIVE_W,
         raise AssertionError(f"live[{backend}]: {done}/{n} completed")
     if backend == "pallas":
         assert_compiled(sess._tick.lower(
-            sess._win_batch, sess._dev_state, None, sess._comp,
-            sess._staged_px, np.int32(0), np.float32(0.0)).as_text(),
+            sess._win_batch, sess._dev_state, None, sess._xfer).as_text(),
             "live[pallas] session tick")
     polls = sess.stats.n_polls
     return {"completed": f"{done}/{n}", "polls": polls,

@@ -18,8 +18,9 @@ is open-ended and windowed:
     epoch — apply the previous epoch's verdicts, ingest completions,
     retire, compact + admit, dispatch — is a single donated-buffer
     `jax.jit` (`_fused_tick`).  The slot pool never leaves the device:
-    the host pushes the newly-staged arrivals plus a narrow completion
-    scatter, and pulls one packed `(4B+2,)` decision summary.  Terminal
+    the host pushes one packed f32 vector (the completion scatter, the
+    newly-staged arrivals, the epoch's scalars and the previous epoch's
+    verdicts) and pulls one packed `(4B+2,)` decision summary.  Terminal
     classification (completed vs abandoned) runs on host-side float32
     mirrors that replay the device's own comparison chains bit-exactly,
     so the per-poll `(W,)` status pulls of the unfused design are gone.
@@ -202,11 +203,38 @@ class _PollSpans:
 # ---------------------------------------------------------------------------
 
 
-# row layout of the packed (7, W) staging transfer: int fields ride
-# exactly in f32 (buckets/classes are tiny) so the host pushes ONE
-# array per poll instead of eight
+# row layout of the (7, W) staging part of the per-poll transfer: int
+# fields ride exactly in f32 (buckets/classes are tiny)
 _ST_ARRIVAL, _ST_BUCKET, _ST_CLS, _ST_TOKENS = 0, 1, 2, 3
 _ST_P50, _ST_P90, _ST_DEADLINE = 4, 5, 6
+
+
+def _xfer_parts(w: int, b: int) -> tuple:
+    """Where each per-poll input of the fused tick lies in the packed
+    host-to-device transfer, one flat f32 vector:
+
+      [comp (2, W) | staged (7, W) | n_stage | now | accepted (B) |
+       delay_ms (B) | resub (K), on sessions with a watchdog only]
+
+    Int fields ride exactly in f32 (`n_stage <= W < 2^24`).  Each host
+    argument of a jitted call is a device put of its own, and a put
+    costs per transfer, not per byte, so the session pushes this one
+    buffer instead of an argument per field.  Returns the (comp,
+    staged, n_stage, now, verdicts, resub) slices and indices."""
+    v = 9 * w + 2
+    return (slice(0, 2 * w), slice(2 * w, 9 * w), 9 * w, 9 * w + 1,
+            slice(v, v + 2 * b), slice(v + 2 * b, None))
+
+
+def _unpack_xfer(xfer, w: int, b: int) -> tuple:
+    """The fused tick's view of the packed transfer (`_xfer_parts`):
+    `(comp, staged, n_stage, now, verdicts, resub)`, with `resub` None
+    where the buffer ends after the verdicts (no watchdog)."""
+    comp, staged, n_stage, now, verdicts, resub = _xfer_parts(w, b)
+    charge = xfer[resub]
+    return (xfer[comp].reshape(2, w), xfer[staged].reshape(7, w),
+            xfer[n_stage].astype(jnp.int32), xfer[now], xfer[verdicts],
+            charge if charge.shape[0] else None)
 
 
 @stages.scoped(stages.ADMIT)
@@ -340,37 +368,52 @@ _apply_decisions = jax.jit(_apply_body, donate_argnums=(2,))
 
 
 def _fused_tick(policy: PolicyConfig, phys: ProviderPhysics,
-                batch: RequestBatch, state: SimState, prev,
-                comp, staged, n_stage, now, resub=None,
+                batch: RequestBatch, state: SimState, prev, xfer,
+                staged=None, n_stage=None, now=None,
                 *, max_grants: int, backend: str):
     """One decision epoch as a single donated-buffer device step:
 
       apply(prev) -> charge resubmits -> ingest completions -> retire
                   -> compact + admit -> dispatch -> packed summary
 
-    `prev` is the previous epoch's `(BatchDecision, accept_delay)` —
-    or None on the first epoch / after an explicit `_state` flush, a
-    distinct pytree structure that traces the no-leading-apply variant;
-    `accept_delay` is the (2B,) packed [accepted; delay_ms] verdict of
-    the host's submit loop.  `batch` and `state` are donated: the (W,)
-    slot pool lives on the device across polls and the host never
-    rematerializes it.  Per poll the host pushes exactly three packed
-    arrays — `comp` (2, W) [slot; finish], `staged` (7, W), and the
-    verdicts — and pulls one summary vector
+    `prev` is the previous epoch's `BatchDecision` — or None on the
+    first epoch / after an explicit `_state` flush, a distinct pytree
+    structure that traces the no-leading-apply variant.  `batch` and
+    `state` are donated: the (W,) slot pool lives on the device across
+    polls and the host never rematerializes it.  Per poll the host
+    pushes exactly one array, the flat f32 `xfer` (`_xfer_parts` gives
+    its layout): `comp` (2, W) [slot; finish], `staged` (7, W), the
+    scalars `n_stage` and `now`, the (2B,) [accepted; delay_ms]
+    verdicts of the previous epoch's submit loop, and on sessions with
+    a watchdog the (K,) resubmit charge.  It pulls one summary vector
     `[actions, req_idx, inflight_at, backoff, severity, next_defer]`
     (int fields ride exactly in f32 throughout).
 
-    `resub` is the (K,) per-class deficit charge for this epoch's
-    watchdog resubmissions — or None on sessions without a resilience
-    layer, where its absence is pytree structure: the None trace is the
-    byte-identical pre-resilience program.  Charged before dispatch so
-    recovery traffic depresses its class's share this very epoch.
+    The resubmit charge is the per-class deficit charge for this
+    epoch's watchdog resubmissions.  Sessions without a resilience
+    layer leave it out of the buffer, and its absence is a static
+    shape: that trace is the pre-resilience program.  Charged before
+    dispatch so recovery traffic depresses its class's share this very
+    epoch.
+
+    `staged` not None selects the separate-argument form `(batch, state,
+    prev, comp, staged, n_stage, now)`, a second pytree structure that
+    packs its arguments on the device and carries no verdicts (`prev`
+    applies as all accepted).  Neither `poll` nor the warm-up calls it;
+    it exists only because bench/tests/test_scopes.py lowers the tick
+    in that form.
     """
+    w = batch.n
+    b = min(max_grants, w)  # schedule_batch's grant width
+    if staged is not None:
+        xfer = jnp.concatenate([
+            xfer.ravel(), staged.ravel(),
+            jnp.stack([jnp.asarray(n_stage, jnp.float32), now]),
+            jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.float32)])
+    comp, staged, n_stage, now, verdicts, resub = _unpack_xfer(xfer, w, b)
     if prev is not None:
-        d0, ad0 = prev
-        b0 = d0.actions.shape[0]
-        state = _apply_body(policy, batch, state, d0,
-                            ad0[:b0] != 0.0, ad0[b0:])
+        state = _apply_body(policy, batch, state, prev,
+                            verdicts[:b] != 0.0, verdicts[b:])
     if resub is not None:
         state = state._replace(sched=state.sched._replace(
             deficit=charge_resubmit(policy, state.sched.deficit, resub)))
@@ -499,7 +542,7 @@ class ClientSession:
         self._win_batch = empty_window_batch(w)
         self._dev_state = init_sim_state(w, self._k)._replace(
             req=empty_window_request_state(w))
-        self._pending = None  # (BatchDecision, accepted, delay) to fold in
+        self._pending = None  # the BatchDecision to fold in next poll
         self._idle_cache: Optional[PollResult] = None
         # host mirrors (kept in lockstep with the device pool; float32
         # fields replay the device's own comparison chains bit-exactly)
@@ -507,7 +550,8 @@ class ClientSession:
         self._arrival_ms: list[float] = []
         # columnar staging features, filled at submit() — queue pops
         # are a contiguous rid range, so staging is 7 list-slice
-        # assigns into the packed transfer buffer, not a per-row loop
+        # assigns into the transfer buffer's staged rows, not a per-row
+        # loop
         self._cols: tuple[list, ...] = tuple([] for _ in range(7))
         self._queue: deque[int] = deque()
         self._slot_rid = np.full(w, -1, np.int64)
@@ -522,20 +566,29 @@ class ClientSession:
         self._t0: Optional[float] = None
         self._defer_hint = float("inf")
         self._timeout_mult = np.asarray(policy.timeout_mult, np.float32)
-        # reused per-poll transfer buffers.  A jitted call may read a
-        # NumPy argument in place (zero-copy on the CPU) until it ends,
-        # so they are refilled only after the poll's blocking pull.
-        self._comp = np.empty((2, w), np.float32)
-        self._comp[0] = w          # scatter sentinel: dropped by the set
-        self._comp[1] = np.inf
-        self._staged_px = np.zeros((7, w), np.float32)
-        self._staged_px[_ST_TOKENS:_ST_P90 + 1] = 1.0
-        self._staged_px[_ST_DEADLINE] = 1e9
         self._watchdog = (Watchdog(resilience, self.phys)
                           if resilience is not None else None)
-        # (K,) per-class deficit charge for this epoch's resubmissions;
-        # reused transfer buffer like _comp
-        self._resub_charge = np.zeros(self._k, np.float32)
+        # the reused per-poll transfer buffer (`_xfer_parts`) and its
+        # parts as views.  A jitted call may read a NumPy argument in
+        # place (zero-copy on the CPU) until it ends, so the buffer is
+        # refilled only after the poll's blocking pull.
+        self._bm = b = min(cfg.max_grants, w)  # schedule_batch's width
+        comp, staged, self._x_nstage, self._x_now, verdicts, resub = \
+            _xfer_parts(w, b)
+        k_resub = self._k if self._watchdog is not None else 0
+        self._xfer = np.zeros(verdicts.stop + k_resub, np.float32)
+        self._comp = self._xfer[comp].reshape(2, w)
+        self._comp[0] = w          # scatter sentinel: dropped by the set
+        self._comp[1] = np.inf
+        self._staged_px = self._xfer[staged].reshape(7, w)
+        self._staged_px[_ST_TOKENS:_ST_P90 + 1] = 1.0
+        self._staged_px[_ST_DEADLINE] = 1e9
+        # [accepted; delay_ms] verdicts of the last submit loop
+        self._accdelay = self._xfer[verdicts]
+        self._accdelay[:b] = 1.0
+        # (K,) per-class deficit charge for this epoch's resubmissions
+        # (empty without a watchdog)
+        self._resub_charge = self._xfer[resub]
         self._tick = _tick_for(policy, self.phys, cfg.max_grants,
                                cfg.backend)
         self._warmup()
@@ -547,11 +600,10 @@ class ClientSession:
         introspection flushes it first so callers always observe the
         state as if the epoch had been applied eagerly."""
         if self._pending is not None:
-            d, ad = self._pending
-            b = self._bm
+            ad, b = self._accdelay, self._bm
             self._dev_state = _apply_decisions(
-                self.policy, self._win_batch, self._dev_state, d,
-                ad[:b] != 0.0, ad[b:].copy())
+                self.policy, self._win_batch, self._dev_state,
+                self._pending, ad[:b] != 0.0, ad[b:].copy())
             self._pending = None
         return self._dev_state
 
@@ -562,25 +614,14 @@ class ClientSession:
         would burn that as session time — at time_scale >> 1 enough to
         blow every deadline before the first decision lands.  Both trace
         variants (with and without the leading apply) and the flush path
-        are warmed; the throwaway buffers are re-initialized after."""
-        w, k = self.cfg.window, self._k
-        zero = np.int32(0)
-        t0 = np.float32(0.0)
-        # resilient sessions always pass the (K,) resubmit charge, so
-        # those are the variants to warm; trusting sessions omit the
-        # argument entirely (distinct trace, byte-identical to the
-        # pre-resilience program)
-        extra = (self._resub_charge,) if self._watchdog is not None else ()
+        are warmed; the throwaway buffers are re-initialized after.
+        The transfer buffer is still in its initial state (no staged
+        rows, all verdicts accepted, `now` 0), and the buffer's length
+        already says whether the resubmit charge is in it."""
+        w, k, bm = self.cfg.window, self._k, self._bm
         batch1, state1, d1, _ = self._tick(
-            self._win_batch, self._dev_state, None,
-            self._comp, self._staged_px, zero, t0, *extra)
-        bm = int(d1.actions.shape[0])
-        self._bm = bm
-        self._accdelay = np.zeros(2 * bm, np.float32)
-        self._accdelay[:bm] = 1.0
-        batch2, state2, d2, _ = self._tick(
-            batch1, state1, (d1, self._accdelay),
-            self._comp, self._staged_px, zero, t0, *extra)
+            self._win_batch, self._dev_state, None, self._xfer)
+        batch2, state2, d2, _ = self._tick(batch1, state1, d1, self._xfer)
         out = _apply_decisions(self.policy, batch2, state2, d2,
                                self._accdelay[:bm] != 0.0,
                                self._accdelay[bm:].copy())
@@ -845,12 +886,13 @@ class ClientSession:
             spans.to("staging")
         staged_rids = self._stage_admissions(now_ms, w - n_alive)
         n_stage = len(staged_rids)
+        xfer = self._xfer
+        xfer[self._x_nstage] = n_stage
+        xfer[self._x_now] = now32
         if spans is not None:
             spans.to("dispatch")
-        extra = (self._resub_charge,) if self._watchdog is not None else ()
         self._win_batch, self._dev_state, d, summary = self._tick(
-            self._win_batch, self._dev_state, self._pending,
-            self._comp, self._staged_px, np.int32(n_stage), now32, *extra)
+            self._win_batch, self._dev_state, self._pending, xfer)
         if spans is not None:
             spans.to("mirrors")
         # the dispatch is async: the mirror bookkeeping below depends
@@ -886,11 +928,11 @@ class ClientSession:
         summary = np.asarray(summary)  # the one device->host pull
         if spans is not None:
             spans.to("grants")
-        # the tick has ended, so its transfer buffers may be reset now
+        # the tick has ended, so its transfer buffer may be reset now
         if ncomp:
             self._comp[0, :ncomp] = w
             self._comp[1, :ncomp] = np.inf
-        if extra and self._resub_charge.any():
+        if self._watchdog is not None and self._resub_charge.any():
             self._resub_charge[:] = 0.0
         actions = summary[0:b].astype(np.int32)
         idxs = summary[b:2 * b].astype(np.int32)
@@ -947,8 +989,9 @@ class ClientSession:
                 self.stats.n_rejected += 1
                 self._unfinished -= 1
 
-        # 7. the device transition folds into the next poll's step
-        self._pending = (d, ad)
+        # 7. the device transition folds into the next poll's step (its
+        # verdicts ride in the transfer buffer)
+        self._pending = d
         self.stats.peak_inflight = max(
             self.stats.peak_inflight, self.provider.inflight())
         hint = dev_next_defer

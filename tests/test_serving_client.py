@@ -32,6 +32,7 @@ from repro.client import (
     ClientSession,
     MockProvider,
     Request,
+    ResilienceConfig,
     SessionConfig,
     default_p90,
     expo_retry,
@@ -40,6 +41,7 @@ from repro.core.policy import strategy
 from repro.core.scheduler import IDLE
 from repro.sim import SimConfig, WorkloadConfig, default_physics, generate, run_sim
 from repro.sim import scenarios as scn
+from repro.sim.faults import FaultSchedule
 from repro.sim.workload import P90_OVER_P50_NP
 
 
@@ -436,6 +438,43 @@ class TestDonationSafety:
         out = sess.drain(max_polls=4000)
         assert all(r.status == "completed" for r in out)
         assert checks and all(checks)
+
+    @pytest.mark.parametrize("resilience", [None, ResilienceConfig(
+        timeout_mult=3.0, max_resubmits=3)], ids=["trusting", "watchdog"])
+    def test_one_host_transfer_per_poll(self, resilience):
+        """Every device poll hands the tick exactly one host-resident
+        argument leaf (the packed transfer buffer): each NumPy array or
+        scalar among a jitted call's arguments is a device put of its
+        own, and a put costs per transfer, not per byte.  The watchdog
+        session (over a provider that drops completions, so it
+        resubmits) carries its resubmit charge in the same buffer."""
+        phys = default_physics()
+        faults = None if resilience is None else FaultSchedule(
+            seed=2, drop_frac=0.3)
+        sess = ClientSession(
+            MockProvider(phys, dt_ms=25.0, faults=faults),
+            strategy("final_adrr_olc"),
+            SessionConfig(window=16, max_grants=2, dt_ms=25.0),
+            clock="virtual", phys=phys, resilience=resilience)
+        batch, jitter = generate(jax.random.PRNGKey(5), WorkloadConfig(
+            n_requests=24, mix="balanced", congestion="high"))
+        for r in batch_to_requests(batch, jitter):
+            sess.submit(r)
+        tick, counts = sess._tick, []
+
+        def spy(*args):
+            counts.append(sum(
+                isinstance(leaf, (np.ndarray, np.generic))
+                for leaf in jax.tree_util.tree_leaves(args)))
+            return tick(*args)
+
+        sess._tick = spy
+        for _ in range(400):
+            sess.poll()
+        assert sess.stats.n_completed > 10
+        assert len(counts) > 10 and set(counts) == {1}
+        if resilience is not None:
+            assert sess.stats.n_resubmitted > 0
 
     def test_post_drain_poll_is_transfer_free(self):
         """After drain() the pool is empty and the epoch is a fixpoint:

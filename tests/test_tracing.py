@@ -63,8 +63,8 @@ def test_live_tick_carries_every_shared_stage_scope():
     sess.poll()
     assert sess._pending is not None
     compiled = sess._tick.lower(
-        sess._win_batch, sess._dev_state, sess._pending, sess._comp,
-        sess._staged_px, np.int32(0), np.float32(25.0)).compile()
+        sess._win_batch, sess._dev_state, sess._pending,
+        sess._xfer).compile()
     assert _scopes(compiled.as_text()) == set(SHARED)
 
 
