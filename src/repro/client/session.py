@@ -83,8 +83,13 @@ from repro.client.request import Request
 from repro.client.resilience import ResilienceConfig, Watchdog
 from repro.core import overload as olc
 from repro.core import stages
-from repro.core.policy import ALLOC_ADRR, PolicyConfig, n_classes
-from repro.core.scheduler import IDLE, charge_resubmit, schedule_batch
+from repro.core.policy import PolicyConfig, n_classes
+from repro.core.scheduler import (
+    IDLE,
+    apply_verdicts,
+    charge_resubmit,
+    schedule_batch,
+)
 from repro.core.types import (
     INFLIGHT,
     PENDING,
@@ -290,73 +295,17 @@ def _compact_and_admit(batch: RequestBatch, req, alive, staged, n_stage):
 @stages.scoped(stages.APPLY)
 def _apply_body(policy: PolicyConfig, batch: RequestBatch,
                 state: SimState, d, accepted, delay_ms):
-    """Post-dispatch state transition on the (W,) pool — the live-path
-    sibling of the engine's `_apply_batch`, with two deliberate
-    differences: admits get finish_ms = inf (the transport decides when
-    work lands; completion arrives via the provider poll), and the
-    throttle verdict comes from the provider's actual submit responses
-    (`accepted`) with the session's retry policy supplying `delay_ms`,
-    instead of an engine-owned token bucket.  Deficit conservation on a
-    bounce matches the engine: the allocation charge is refunded
-    (ADRR-gated) because the 429 blocked the release."""
-    w = batch.n
-    req = state.req
+    """Post-dispatch state transition on the (W,) pool: the shared
+    `apply_verdicts`, with the verdicts the live boundary gives.  Admits
+    get no finish time (the transport decides when work lands;
+    completion arrives via the provider poll), and the bounce comes
+    from the provider's actual submit responses (`accepted`), parked
+    for the session retry policy's `delay_ms`, instead of an
+    engine-owned token bucket."""
     admit = (d.actions == olc.ADMIT) & accepted
     throttled = (d.actions == olc.ADMIT) & ~accepted
-    defer = d.actions == olc.DEFER
-    reject = d.actions == olc.REJECT
-    idx = d.req_idx
-    drop = jnp.int32(w)
-    adm_i = jnp.where(admit, idx, drop)
-    def_i = jnp.where(defer, idx, drop)
-    rej_i = jnp.where(reject, idx, drop)
-    thr_i = jnp.where(throttled, idx, drop)
-
-    backoff = olc.defer_backoff(policy, d.severity, req.n_defers[idx])
-
-    status = req.status.at[adm_i].set(INFLIGHT, mode="drop")
-    status = status.at[rej_i].set(REJECTED, mode="drop")
-    submit = req.submit_ms.at[adm_i].set(state.now_ms, mode="drop")
-    defer_until = req.defer_until.at[def_i].set(
-        state.now_ms + backoff, mode="drop")
-    defer_until = defer_until.at[thr_i].set(
-        state.now_ms + delay_ms, mode="drop")
-    n_defers = req.n_defers.at[def_i].add(1, mode="drop")
-    n_throttles = req.n_throttles.at[thr_i].add(1, mode="drop")
-
-    deficit = d.deficit
-    k = deficit.shape[0]
-    gcls = jnp.clip(batch.cls[idx], 0, k - 1)
-    refund = (
-        jax.nn.one_hot(gcls, k)
-        * batch.p50[idx][:, None]
-        * throttled[:, None]
-    ).sum(axis=0) * (policy.alloc_mode == ALLOC_ADRR)
-    # gate on an actual bounce so the no-throttle path returns d.deficit
-    # bit-unchanged (x + 0.0 is not an f32 identity at -0.0)
-    deficit = jnp.where(
-        throttled.any() & jnp.isfinite(deficit + refund).all(),
-        deficit + refund, deficit)
-
-    inflight = state.provider.inflight + admit.sum().astype(jnp.int32)
-    inflight_tokens = state.provider.inflight_tokens + jnp.where(
-        admit, batch.p50[idx], 0.0).sum()
-    return state._replace(
-        req=req._replace(
-            status=status,
-            submit_ms=submit,
-            defer_until=defer_until,
-            n_defers=n_defers,
-            n_throttles=n_throttles,
-        ),
-        sched=state.sched._replace(deficit=deficit, rr_turn=d.rr_turn),
-        provider=state.provider._replace(
-            inflight=inflight,
-            inflight_tokens=inflight_tokens,
-            n_throttled=state.provider.n_throttled
-            + throttled.sum().astype(jnp.int32),
-        ),
-    )
+    return apply_verdicts(policy, batch, state, d, admit, throttled,
+                          retry_ms=delay_ms)
 
 
 # standalone jit of the transition, used only when `session._state` is

@@ -19,8 +19,10 @@ global inflight caps, and the FQ pointer update cumulatively per grant.
 With max_grants=1 the pass reduces bit-exactly to `schedule_slot`.
 
 Both entry points are consumed by the simulation engine
-(repro.sim.engine) and the live serving adapter (repro.serving.blackbox),
-so the policy logic is written once.
+(repro.sim.engine) and the live client session (repro.client.session),
+so the policy logic is written once; so is the state transition the
+decisions make (`apply_verdicts`), which both drivers apply their
+verdicts through.
 
 Fleet dispatch (DESIGN.md §10) slots in *above* these layers: when a
 `(N,)` endpoint assignment and `(N,)` route-cost vector are provided
@@ -46,7 +48,7 @@ import jax.numpy as jnp
 
 from repro.core import drr, ordering, overload, stages
 from repro.core.policy import ALLOC_ADRR, PolicyConfig, n_classes
-from repro.core.types import INFLIGHT, RequestBatch, SimState
+from repro.core.types import INFLIGHT, REJECTED, RequestBatch, SimState
 
 
 class SlotDecision(NamedTuple):
@@ -127,6 +129,97 @@ def charge_resubmit(cfg: PolicyConfig, deficit: jnp.ndarray,
         (charge > 0.0).any() & jnp.isfinite(debited).all()
         & (cfg.alloc_mode == ALLOC_ADRR),
         debited, deficit)
+
+
+def apply_verdicts(cfg: PolicyConfig, batch: RequestBatch, state: SimState,
+                   d: BatchDecision, admit: jnp.ndarray,
+                   throttled: Optional[jnp.ndarray], retry_ms,
+                   finish: Optional[jnp.ndarray] = None) -> SimState:
+    """The state transition of one tick's verdicts, as one set of
+    scatters — the simulation engine and the live session both apply
+    their decisions through this.
+
+    `admit` marks the ADMIT rows that reached the provider and
+    `throttled` the ADMIT rows it bounced 429-style; the drivers differ
+    only in where those verdicts come from (the engine's token bucket,
+    the provider's submit responses).  A bounced request stays PENDING,
+    parked until `now + retry_ms` (a scalar Retry-After, or a `(B,)`
+    per-grant delay), and its DRR charge is refunded like any blocked
+    release.  `throttled=None` means the driver has no bounce source:
+    like every optional mechanism it is pytree structure, and the
+    bounce ops are not traced at all.  `finish` is the admits' landing
+    time where the driver knows it (the engine's service physics); the
+    live transport decides it later, so without it `finish_ms` is left
+    as it is.
+
+    Grants target distinct requests by construction (each consumes a
+    distinct entry of the ranked candidate lists), so the scatters never
+    collide; rows not selected by a mask are routed to the out-of-range
+    index `batch.n` and dropped.
+    """
+    req = state.req
+    defer = d.actions == overload.DEFER
+    reject = d.actions == overload.REJECT
+    idx = d.req_idx
+    drop = jnp.int32(batch.n)  # out-of-range => mode="drop" is a no-op
+    adm_i = jnp.where(admit, idx, drop)
+    def_i = jnp.where(defer, idx, drop)
+    rej_i = jnp.where(reject, idx, drop)
+
+    backoff = overload.defer_backoff(cfg, d.severity, req.n_defers[idx])
+
+    status = req.status.at[adm_i].set(INFLIGHT, mode="drop")
+    status = status.at[rej_i].set(REJECTED, mode="drop")
+    submit = req.submit_ms.at[adm_i].set(state.now_ms, mode="drop")
+    finish_ms = req.finish_ms if finish is None else \
+        req.finish_ms.at[adm_i].set(finish, mode="drop")
+    defer_until = req.defer_until.at[def_i].set(
+        state.now_ms + backoff, mode="drop")
+    n_throttles = req.n_throttles
+    deficit = d.deficit
+    n_throttled = state.provider.n_throttled
+    if throttled is not None:
+        thr_i = jnp.where(throttled, idx, drop)
+        defer_until = defer_until.at[thr_i].set(
+            state.now_ms + retry_ms, mode="drop")
+        n_throttles = n_throttles.at[thr_i].add(1, mode="drop")
+        n_throttled = n_throttled + throttled.sum().astype(jnp.int32)
+        # deficit conservation: the allocation layer charged for these
+        # sends; the 429 blocked the release, so credit it back exactly
+        # like a defer/reject refund (ADRR only)
+        k = deficit.shape[0]
+        gcls = jnp.clip(batch.cls[idx], 0, k - 1)
+        refund = (
+            jax.nn.one_hot(gcls, k)
+            * batch.p50[idx][:, None]
+            * throttled[:, None]
+        ).sum(axis=0) * (cfg.alloc_mode == ALLOC_ADRR)
+        # gate on an actual bounce so the no-throttle path returns
+        # d.deficit bit-unchanged (x + 0.0 is not an f32 identity at -0.0)
+        deficit = jnp.where(
+            throttled.any() & jnp.isfinite(deficit + refund).all(),
+            deficit + refund, deficit)
+    n_defers = req.n_defers.at[def_i].add(1, mode="drop")
+
+    inflight = state.provider.inflight + admit.sum().astype(jnp.int32)
+    inflight_tokens = state.provider.inflight_tokens + jnp.where(
+        admit, batch.p50[idx], 0.0).sum()
+    return state._replace(
+        req=req._replace(
+            status=status,
+            submit_ms=submit,
+            finish_ms=finish_ms,
+            defer_until=defer_until,
+            n_defers=n_defers,
+            n_throttles=n_throttles,
+        ),
+        sched=state.sched._replace(deficit=deficit, rr_turn=d.rr_turn),
+        provider=state.provider._replace(
+            inflight=inflight,
+            inflight_tokens=inflight_tokens,
+            n_throttled=n_throttled,
+        ),
+    )
 
 
 def schedule_slot(

@@ -14,7 +14,9 @@ Each tick:
                                          mode explicit shedding replaces)
   3. ONE batched dispatch pass (`schedule_batch`, DESIGN.md §3): up to
      `k_slots` grants from a single vectorized allocation -> ordering ->
-     overload evaluation, applied as one scatter.  The per-tick policy
+     overload evaluation, applied as one set of scatters by
+     `apply_verdicts` (core.scheduler), the transition the live session
+     applies its verdicts through too.  The per-tick policy
      cost is O(K·N + B·K) instead of the O(B·K·N) the former sequential
      slot loop paid; with k_slots=1 the tick is bit-exact with the
      sequential `schedule_slot` path.
@@ -64,8 +66,8 @@ import jax.numpy as jnp
 from repro.core import overload as olc
 from repro.core import stages
 from repro.core.numerics import pinned
-from repro.core.policy import ALLOC_ADRR, PolicyConfig, n_classes
-from repro.core.scheduler import BatchDecision, schedule_batch
+from repro.core.policy import PolicyConfig, n_classes
+from repro.core.scheduler import BatchDecision, apply_verdicts, schedule_batch
 from repro.core.routing import route_requests
 from repro.core.types import (
     ABANDONED,
@@ -83,6 +85,7 @@ from repro.core.types import (
 )
 from repro.sim.provider import (
     Fleet,
+    FleetDynamics,
     ProviderDynamics,
     ProviderPhysics,
     service_time_ms,
@@ -266,6 +269,30 @@ def _complete_and_timeout(
     )
 
 
+def _limiter(dynamics: ProviderDynamics | None,
+             fleet: Fleet | None) -> ProviderDynamics | FleetDynamics | None:
+    """The dynamics that carry the run's token-bucket limiter: the
+    fleet's when the run has a fleet axis, else the provider's; None
+    when the run has no limiter."""
+    dyn = fleet.dyn if fleet is not None else dynamics
+    return dyn if dyn is not None and dyn.tb_refill is not None else None
+
+
+def _buckets(state: SimState) -> jnp.ndarray:
+    """The token buckets the tick carries: the fleet's (P, K) grid when
+    the state has a fleet axis, else the provider's (K,) row."""
+    return state.provider.tb_tokens if state.fleet is None \
+        else state.fleet.tb_tokens
+
+
+def _with_buckets(state: SimState, tokens: jnp.ndarray) -> SimState:
+    """`state` with `tokens` as the buckets `_buckets` reads."""
+    if state.fleet is None:
+        return state._replace(
+            provider=state.provider._replace(tb_tokens=tokens))
+    return state._replace(fleet=state.fleet._replace(tb_tokens=tokens))
+
+
 @stages.scoped(stages.APPLY)
 def _apply_batch(
     cfg: PolicyConfig,
@@ -275,54 +302,32 @@ def _apply_batch(
     state: SimState,
     d: BatchDecision,
     comfort_scale=None,
-    limiter: ProviderDynamics | None = None,
+    limiter: ProviderDynamics | FleetDynamics | None = None,
     fleet: Fleet | None = None,
 ) -> SimState:
-    """State transition for up to B grants, as one set of scatters.
+    """The engine's side of applying up to B grants: service physics,
+    the provider-boundary token bucket, and the fleet's endpoint
+    bookkeeping.  The transition itself is `apply_verdicts`, shared
+    with the live session.
 
-    Grants target distinct requests by construction (each consumes a
-    distinct entry of the ranked candidate lists), so the scatters never
-    collide; idle rows are routed to the out-of-range index N and
-    dropped.
+    `comfort_scale` is this tick's brownout value (None = stationary).
+    `limiter` is the dynamics whose token bucket guards the provider
+    boundary (None = no limiter): an ADMIT whose bucket is out of
+    grants bounces 429-style with the limiter's Retry-After.  Grants
+    later in the same batch were decided against the optimistic
+    inflight count (the client only observes the bounce after the
+    send), which matches a real async client racing its own rate limit.
 
-    `comfort_scale` is this tick's brownout value (None = stationary);
-    `limiter` enables the provider-boundary token bucket: an ADMIT whose
-    class bucket is out of grants bounces 429-style — the request stays
-    PENDING with `defer_until = now + retry_after` (the client-visible
-    retry) and the DRR charge is refunded like any blocked release.
-    Grants later in the same batch were decided against the optimistic
-    inflight count (the client only observes the bounce after the send),
-    which matches a real async client racing its own rate limit.
-
-    `fleet` (mutually exclusive with `limiter`) switches to the (P,)
-    provider axis: each grant lands on its `d.provider_idx` endpoint —
-    service physics gather that endpoint's curve at *its* outstanding
-    count, the rate limiter becomes the (P, K) per-endpoint bucket grid
-    (rank arithmetic over the flattened P*K keys), and the request
-    records its endpoint for the failover requeue.  At P == 1 the
-    gathers collapse to endpoint 0 and the arithmetic is the exact
+    `fleet` switches to the (P,) provider axis: each grant lands on its
+    `d.provider_idx` endpoint — service physics gather that endpoint's
+    curve at *its* outstanding count, the buckets are the (P, K)
+    per-endpoint grid (one bucket key per endpoint and class), and the
+    request records its endpoint for the failover requeue.  At P == 1
+    the gathers collapse to endpoint 0 and the arithmetic is the exact
     single-provider program (the fleet P=1 bit-exactness contract).
     """
-    n = batch.n
-    req = state.req
-    admit = d.actions == olc.ADMIT
-    defer = d.actions == olc.DEFER
-    reject = d.actions == olc.REJECT
     idx = d.req_idx
-    deficit = d.deficit
-
-    if limiter is not None:
-        k = state.provider.tb_tokens.shape[0]
-        gcls = jnp.clip(batch.cls[idx], 0, k - 1)
-        # g-th grant's rank among this batch's admits of the same class:
-        # admit is allowed iff the bucket holds that many grants
-        take = (gcls[:, None] == jnp.arange(k, dtype=jnp.int32)) & admit[:, None]
-        rank = (jnp.cumsum(take, axis=0) * take).sum(axis=-1)  # (B,) 1-based
-        allowed = rank.astype(jnp.float32) <= state.provider.tb_tokens[gcls] + 1e-6
-        throttled = admit & ~allowed
-        admit = admit & allowed
-
-    fl_limited = False
+    admit = d.actions == olc.ADMIT
     if fleet is not None:
         p = fleet.phys.base_ms.shape[0]
         ep = jnp.clip(d.provider_idx, 0, p - 1)
@@ -330,20 +335,25 @@ def _apply_batch(
         # mirrors d.inflight_at's optimism — the client only observes a
         # 429 after the send
         admit0 = admit
-        if fleet.dyn is not None and fleet.dyn.tb_refill is not None:
-            fl_limited = True
-            k = state.fleet.tb_tokens.shape[1]
-            gcls = jnp.clip(batch.cls[idx], 0, k - 1)
-            # same rank-vs-bucket rule as the single-provider limiter,
-            # over the flattened (P*K,) bucket keys
-            key = ep * k + gcls
-            take = (key[:, None] == jnp.arange(p * k, dtype=jnp.int32)) \
-                & admit[:, None]
-            rank = (jnp.cumsum(take, axis=0) * take).sum(axis=-1)
-            allowed = rank.astype(jnp.float32) <= \
-                state.fleet.tb_tokens.reshape(p * k)[key] + 1e-6
-            throttled = admit & ~allowed
-            admit = admit & allowed
+    throttled = retry_ms = None
+    if limiter is not None:
+        # the rank-vs-bucket rule over flat bucket keys (a class, or an
+        # endpoint and class on the fleet's grid): the g-th admit of the
+        # batch that draws on a bucket passes iff it holds g grants
+        tokens = _buckets(state)
+        k = tokens.shape[-1]
+        flat = tokens.reshape(-1)
+        gcls = jnp.clip(batch.cls[idx], 0, k - 1)
+        key = gcls if fleet is None else ep * k + gcls
+        take = (key[:, None] == jnp.arange(flat.shape[0], dtype=jnp.int32)) \
+            & admit[:, None]
+        rank = (jnp.cumsum(take, axis=0) * take).sum(axis=-1)  # (B,) 1-based
+        allowed = rank.astype(jnp.float32) <= flat[key] + 1e-6
+        throttled = admit & ~allowed
+        admit = admit & allowed
+        consumed = (take & admit[:, None]).sum(axis=0).astype(jnp.float32)
+        state = _with_buckets(state, tokens - consumed.reshape(tokens.shape))
+        retry_ms = limiter.retry_after_ms
 
     # per-grant service physics at the inflight level the grant saw —
     # identical floats to the sequential one-admit-at-a-time path.
@@ -382,108 +392,33 @@ def _apply_batch(
             phys_g, batch.true_tokens[idx], infl_ep, jitter[idx], comfort_g
         )
     finish = state.now_ms + service
-    backoff = olc.defer_backoff(cfg, d.severity, req.n_defers[idx])
+    state = apply_verdicts(cfg, batch, state, d, admit, throttled, retry_ms,
+                           finish=finish)
 
-    drop = jnp.int32(n)  # out-of-range => mode="drop" makes the row a no-op
-    adm_i = jnp.where(admit, idx, drop)
-    def_i = jnp.where(defer, idx, drop)
-    rej_i = jnp.where(reject, idx, drop)
-
-    status = req.status.at[adm_i].set(INFLIGHT, mode="drop")
-    status = status.at[rej_i].set(REJECTED, mode="drop")
-    submit = req.submit_ms.at[adm_i].set(state.now_ms, mode="drop")
-    finish_ms = req.finish_ms.at[adm_i].set(finish, mode="drop")
-    defer_until = req.defer_until.at[def_i].set(
-        state.now_ms + backoff, mode="drop")
-    n_defers = req.n_defers.at[def_i].add(1, mode="drop")
-    n_throttles = req.n_throttles
-
-    provider = state.provider
-    if limiter is not None:
-        thr_i = jnp.where(throttled, idx, drop)
-        defer_until = defer_until.at[thr_i].set(
-            state.now_ms + limiter.retry_after_ms, mode="drop")
-        n_throttles = n_throttles.at[thr_i].add(1, mode="drop")
-        consumed = (take & admit[:, None]).sum(axis=0).astype(jnp.float32)
-        provider = provider._replace(
-            tb_tokens=provider.tb_tokens - consumed,
-            n_throttled=provider.n_throttled
-            + throttled.sum().astype(jnp.int32),
-        )
-        # deficit conservation: the allocation layer charged for these
-        # sends inside schedule_batch; the 429 blocked the release, so
-        # credit it back exactly like a defer/reject refund (ADRR only).
-        refund = (
-            jax.nn.one_hot(gcls, k)
-            * batch.p50[idx][:, None]
-            * throttled[:, None]
-        ).sum(axis=0) * (cfg.alloc_mode == ALLOC_ADRR)
-        deficit = jnp.where(jnp.isfinite(deficit + refund),
-                            deficit + refund, deficit)
-
-    fstate = state.fleet
-    endpoint = req.endpoint
     if fleet is not None:
         # record where each admit went (the failover requeue and the
         # per-endpoint recount both read this) and split the aggregate
         # updates along the endpoint axis
-        endpoint = endpoint.at[adm_i].set(ep, mode="drop")
-        adm_oh = jax.nn.one_hot(ep, p, dtype=jnp.int32) * admit[:, None]
+        fstate = state.fleet
+        adm_i = jnp.where(admit, idx, jnp.int32(batch.n))
+        ep_oh = jax.nn.one_hot(ep, p, dtype=jnp.int32)
+        adm_oh = ep_oh * admit[:, None]
         fstate = fstate._replace(
             inflight=fstate.inflight + adm_oh.sum(axis=0).astype(jnp.int32),
             inflight_tokens=fstate.inflight_tokens
             + (adm_oh.astype(jnp.float32) * batch.p50[idx][:, None]).sum(
                 axis=0),
         )
-        if fl_limited:
-            thr_i = jnp.where(throttled, idx, drop)
-            defer_until = defer_until.at[thr_i].set(
-                state.now_ms + fleet.dyn.retry_after_ms, mode="drop")
-            n_throttles = n_throttles.at[thr_i].add(1, mode="drop")
-            consumed = (take & admit[:, None]).sum(axis=0).astype(
-                jnp.float32).reshape(p, k)
-            thr_oh = jax.nn.one_hot(ep, p, dtype=jnp.int32) \
-                * throttled[:, None]
+        if throttled is not None:
             fstate = fstate._replace(
-                tb_tokens=fstate.tb_tokens - consumed,
                 n_throttled=fstate.n_throttled
-                + thr_oh.sum(axis=0).astype(jnp.int32),
-            )
-            # deficit conservation — same refund as the single-provider
-            # limiter: the 429 blocked a charged release (ADRR only)
-            refund = (
-                jax.nn.one_hot(gcls, k)
-                * batch.p50[idx][:, None]
-                * throttled[:, None]
-            ).sum(axis=0) * (cfg.alloc_mode == ALLOC_ADRR)
-            deficit = jnp.where(jnp.isfinite(deficit + refund),
-                                deficit + refund, deficit)
-            provider = provider._replace(
-                n_throttled=provider.n_throttled
-                + throttled.sum().astype(jnp.int32),
-            )
-
-    inflight = provider.inflight + admit.sum().astype(jnp.int32)
-    inflight_tokens = provider.inflight_tokens + jnp.where(
-        admit, batch.p50[idx], 0.0
-    ).sum()
-
-    return state._replace(
-        req=req._replace(
-            status=status,
-            submit_ms=submit,
-            finish_ms=finish_ms,
-            defer_until=defer_until,
-            n_defers=n_defers,
-            n_throttles=n_throttles,
-            endpoint=endpoint,
-        ),
-        sched=state.sched._replace(deficit=deficit, rr_turn=d.rr_turn),
-        provider=provider._replace(
-            inflight=inflight, inflight_tokens=inflight_tokens
-        ),
-        fleet=fstate,
-    )
+                + (ep_oh * throttled[:, None]).sum(axis=0).astype(jnp.int32))
+        state = state._replace(
+            req=state.req._replace(
+                endpoint=state.req.endpoint.at[adm_i].set(ep, mode="drop")),
+            fleet=fstate,
+        )
+    return state
 
 
 def pack_batch(batch: RequestBatch) -> jnp.ndarray:
@@ -649,8 +584,11 @@ def sim_tick(
 
     This is THE per-tick program — `run_sim` scans it, and the live
     `ClientSession` fused tick is its transport-boundary sibling
-    (retire/compact/dispatch are the same functions there; apply is
-    split across the provider round-trip).  Module-level and explicit
+    (retire, dispatch and the verdict transition `apply_verdicts` are
+    the same functions there; the session applies a tick's verdicts at
+    the start of its next poll, once the provider has answered the
+    submits, and compacts its slot pool with its own
+    `_compact_and_admit`).  Module-level and explicit
     so the two paths share one definition of the tick, not two copies
     that drift.  `win=None` runs the dense O(N) transition; a
     `WindowCarry` runs the O(W) active-window path.  `fleet` switches
@@ -665,9 +603,8 @@ def sim_tick(
     per-tick decision trace row (or None).
     """
     windowed = win is not None
-    has_limiter = dynamics is not None and dynamics.tb_refill is not None
     fl_dyn = fleet.dyn if fleet is not None else None
-    has_fleet_limiter = fl_dyn is not None and fl_dyn.tb_refill is not None
+    limiter = _limiter(dynamics, fleet)
     t_idx, comfort_t, refill_t, avail_t = xs
     retry_ms = fl_dyn.retry_after_ms if avail_t is not None else None
     now = (t_idx + 1).astype(jnp.float32) * dt_ms
@@ -682,24 +619,9 @@ def sim_tick(
         state = _complete_and_timeout(policy, phys, batch, state,
                                       avail_t=avail_t,
                                       retry_after_ms=retry_ms)
-    if has_limiter:
-        state = state._replace(
-            provider=state.provider._replace(
-                tb_tokens=jnp.minimum(
-                    state.provider.tb_tokens + refill_t,
-                    dynamics.tb_capacity,
-                )
-            )
-        )
-    if has_fleet_limiter:
-        state = state._replace(
-            fleet=state.fleet._replace(
-                tb_tokens=jnp.minimum(
-                    state.fleet.tb_tokens + refill_t,
-                    fl_dyn.tb_capacity,
-                )
-            )
-        )
+    if limiter is not None:
+        state = _with_buckets(state, jnp.minimum(
+            _buckets(state) + refill_t, limiter.tb_capacity))
     if windowed:
         # the admitted window's view is the admission stage's output
         with jax.named_scope(stages.ADMIT):
@@ -716,8 +638,8 @@ def sim_tick(
                 endpoint, route = route_requests(
                     fleet.phys, state.fleet, d_batch.p50,
                     comfort_t=comfort_t, avail_t=avail_t,
-                    retry_after_ms=fl_dyn.retry_after_ms
-                    if has_fleet_limiter else None,
+                    retry_after_ms=None if limiter is None
+                    else limiter.retry_after_ms,
                 )
         else:
             # static P == 1: no routing choice exists — endpoint is an
@@ -743,7 +665,7 @@ def sim_tick(
     state = _apply_batch(
         policy, phys, batch, jitter, state, d,
         comfort_scale=comfort_t,
-        limiter=dynamics if has_limiter else None,
+        limiter=limiter,
         fleet=fleet,
     )
     ys = (d.actions, d.req_idx, d.severity) if collect_decisions else None
@@ -797,23 +719,17 @@ def run_sim(
     windowed = sim_cfg.window is not None
     state0 = init_sim_state(n, n_classes(policy))
     has_brownout = dynamics is not None and dynamics.comfort_scale is not None
-    has_limiter = dynamics is not None and dynamics.tb_refill is not None
-    if has_limiter:
-        # buckets start full: the burst capacity is available at t=0
-        state0 = state0._replace(
-            provider=state0.provider._replace(tb_tokens=dynamics.tb_capacity)
-        )
     fl_dyn = fleet.dyn if fleet is not None else None
-    has_fleet_limiter = fl_dyn is not None and fl_dyn.tb_refill is not None
+    limiter = _limiter(dynamics, fleet)
     if fleet is not None:
         p = fleet.phys.base_ms.shape[0]
-        fstate0 = init_fleet_state(p, n_classes(policy))
-        if has_fleet_limiter:
-            fstate0 = fstate0._replace(tb_tokens=fl_dyn.tb_capacity)
         state0 = state0._replace(
             req=state0.req._replace(endpoint=jnp.zeros((n,), jnp.int32)),
-            fleet=fstate0,
+            fleet=init_fleet_state(p, n_classes(policy)),
         )
+    if limiter is not None:
+        # buckets start full: the burst capacity is available at t=0
+        state0 = _with_buckets(state0, limiter.tb_capacity)
 
     def tick(carry, xs):
         state, win = carry
@@ -836,8 +752,7 @@ def run_sim(
         jnp.arange(sim_cfg.n_ticks),
         fl_dyn.comfort_scale if fl_dyn is not None
         else (dynamics.comfort_scale if has_brownout else None),
-        fl_dyn.tb_refill if has_fleet_limiter
-        else (dynamics.tb_refill if has_limiter else None),
+        None if limiter is None else limiter.tb_refill,
         fl_dyn.avail if fl_dyn is not None else None,
     )
     (final, win), trace = jax.lax.scan(tick, (state0, win0), xs)

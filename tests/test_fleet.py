@@ -22,12 +22,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.policy import base_policy, strategy
+from repro.core.policy import base_policy, n_classes, strategy
 from repro.core.routing import UNAVAIL_MS, route_requests
 from repro.core.types import COMPLETED, init_fleet_state
 from repro.sim import (
     Fleet,
     FleetDynamics,
+    ProviderDynamics,
     SimConfig,
     WorkloadConfig,
     default_physics,
@@ -82,20 +83,39 @@ def _assert_same_run(a, b, *, compare_endpoint=False):
 class TestP1Transparency:
     """fleet=Fleet(P=1) must compile to the single-provider program."""
 
+    @pytest.mark.parametrize("limiter", ["off", "on"])
     @pytest.mark.parametrize("seed", [0, 1])
-    def test_bit_exact_with_plain_engine(self, seed):
+    def test_bit_exact_with_plain_engine(self, seed, limiter):
+        """With the limiter on, the plain engine's (K,) bucket and the
+        P=1 fleet's (1, K) grid must bounce the same grants, through
+        the same verdict transition."""
         policy = strategy("final_adrr_olc")
         wl = WorkloadConfig(n_requests=120, mix="heavy", congestion="high")
         batch, jitter = generate(jax.random.PRNGKey(seed), wl)
         phys = default_physics()
         sim_cfg = SimConfig(n_ticks=2000, k_slots=4)
+        dyn, fl = None, _mk_fleet(1)
+        if limiter == "on":
+            k = n_classes(policy)
+            refill = jnp.full((sim_cfg.n_ticks, k), 0.02, jnp.float32)
+            cap = jnp.full((k,), 2.0, jnp.float32)
+            dyn = ProviderDynamics(comfort_scale=None, tb_refill=refill,
+                                   tb_capacity=cap,
+                                   retry_after_ms=jnp.float32(1500.0))
+            fl = _mk_fleet(1, tb_refill=refill[:, None, :],
+                           tb_capacity=cap[None, :])
         plain = jax.jit(lambda: run_sim(
-            policy, batch, jitter, phys, sim_cfg,
+            policy, batch, jitter, phys, sim_cfg, dyn,
             collect_decisions=True))()
         fleet = jax.jit(lambda: run_sim(
             policy, batch, jitter, phys, sim_cfg,
-            fleet=_mk_fleet(1), collect_decisions=True))()
+            fleet=fl, collect_decisions=True))()
         _assert_same_run(plain, fleet)
+        n_thr = int(plain[0].provider.n_throttled)
+        assert int(fleet[0].provider.n_throttled) == n_thr
+        assert int(np.asarray(fleet[0].fleet.n_throttled).sum()) == n_thr
+        if limiter == "on":
+            assert n_thr >= 1, "the limiter never bounced a grant"
         # service times bit-identical: the P==1 gather must reproduce
         # the exact scalar physics program, not a re-rounded variant
         assert _bits_equal(plain[0].req.finish_ms, fleet[0].req.finish_ms)

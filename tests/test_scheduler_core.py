@@ -12,8 +12,16 @@ from repro.core.policy import (
     with_bucket_policy,
     with_information,
 )
-from repro.core.scheduler import IDLE, schedule_slot
+from repro.core.scheduler import (
+    IDLE,
+    BatchDecision,
+    apply_verdicts,
+    schedule_slot,
+)
 from repro.core.types import (
+    INFLIGHT,
+    PENDING,
+    REJECTED,
     RequestBatch,
     SHORT,
     XLONG,
@@ -290,3 +298,60 @@ class TestScheduleSlot:
         st0 = init_sim_state(8)._replace(now_ms=jnp.float32(100.0))
         d = jax.jit(schedule_slot)(cfg, b, st0)
         assert d.action.shape == ()
+
+
+# ---------------------------------------------------------------------------
+# The verdict transition both drivers share
+# ---------------------------------------------------------------------------
+
+class TestApplyVerdicts:
+    def test_each_verdict_scatters_once(self):
+        """One batch with an admit, a defer, a reject, a 429 bounce and
+        an IDLE row: each verdict writes its own fields of its own row
+        and nothing else; without a bounce the deficits come back
+        bit-unchanged, a -0.0 entry included."""
+        cfg = strategy("final_adrr_olc")
+        b = mk_batch(6, bucket=[0, 2, 3, 1, 0, 2],
+                     p50=[100.0, 200.0, 300.0, 400.0, 50.0, 60.0])
+        st = init_sim_state(6)._replace(now_ms=jnp.float32(1000.0))
+        st = st._replace(req=st.req._replace(
+            n_defers=jnp.asarray([0, 1, 0, 0, 0, 0], jnp.int32)))
+        act = jnp.asarray([overload.ADMIT, overload.DEFER, overload.REJECT,
+                           overload.ADMIT, IDLE], jnp.int32)
+        d = BatchDecision(
+            actions=act, req_idx=jnp.asarray([0, 1, 2, 3, 4], jnp.int32),
+            inflight_at=jnp.zeros((5,), jnp.int32),
+            severity=jnp.float32(0.5),
+            deficit=jnp.asarray([-0.0, 7.0], jnp.float32),
+            rr_turn=jnp.int32(1))
+        admit = act == overload.ADMIT
+        bounced = jnp.asarray([False, False, False, True, False])
+        retry = jnp.asarray([0.0, 0.0, 0.0, 1500.0, 0.0], jnp.float32)
+        finish = jnp.full((5,), 1234.0, jnp.float32)
+        out = apply_verdicts(cfg, b, st, d, admit & ~bounced, bounced,
+                             retry, finish=finish)
+        r = out.req
+        assert r.status.tolist() == [INFLIGHT, PENDING, REJECTED, PENDING,
+                                     PENDING, PENDING]
+        inf = float("inf")
+        assert r.submit_ms.tolist() == [1000.0] + [inf] * 5
+        assert r.finish_ms.tolist() == [1234.0] + [inf] * 5
+        backoff = float(overload.defer_backoff(cfg, d.severity, 1))
+        assert r.defer_until.tolist() == [0.0, 1000.0 + backoff, 0.0,
+                                          2500.0, 0.0, 0.0]
+        assert r.n_defers.tolist() == [0, 2, 0, 0, 0, 0]
+        assert r.n_throttles.tolist() == [0, 0, 0, 1, 0, 0]
+        # the bounce refunds its class's charge (request 3 is class 1)
+        assert out.sched.deficit.tolist() == [0.0, 407.0]
+        assert int(out.sched.rr_turn) == 1
+        assert int(out.provider.n_throttled) == 1
+        assert int(out.provider.inflight) == 1
+        assert float(out.provider.inflight_tokens) == 100.0
+
+        for no_bounce in (jnp.zeros((5,), bool), None):
+            out = apply_verdicts(cfg, b, st, d, admit, no_bounce, 0.0)
+            assert out.sched.deficit.tobytes() == d.deficit.tobytes()
+            assert int(out.provider.n_throttled) == 0
+            assert int(out.provider.inflight) == 2
+            # without `finish` the transport owns the landing time
+            assert np.isinf(np.asarray(out.req.finish_ms)).all()

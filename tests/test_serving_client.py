@@ -24,6 +24,7 @@ from __future__ import annotations
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -39,7 +40,14 @@ from repro.client import (
 )
 from repro.core.policy import strategy
 from repro.core.scheduler import IDLE
-from repro.sim import SimConfig, WorkloadConfig, default_physics, generate, run_sim
+from repro.sim import (
+    ProviderDynamics,
+    SimConfig,
+    WorkloadConfig,
+    default_physics,
+    generate,
+    run_sim,
+)
 from repro.sim import scenarios as scn
 from repro.sim.faults import FaultSchedule
 from repro.sim.workload import P90_OVER_P50_NP
@@ -127,6 +135,42 @@ class TestDecisionParity:
                                  k_slots=4, policy_name="final_adrr_olc")
         assert_decision_parity(trace, *drive_session(sess, 900))
 
+    def test_token_bucket_bounces(self):
+        """The `balanced` pin's inputs under a rate_crunch-style token
+        bucket, given to both sides: the engine's bucket and the
+        provider's 429s must bounce the same grants, and the bounce
+        must apply the same way (Retry-After park, throttle count, DRR
+        refund), or the decision streams part."""
+        n_ticks, dt = 900, 25.0
+        t = np.arange(n_ticks)
+        # 1.2 grants/s per class, frozen to 10% for the middle third
+        refill = np.full((n_ticks, 2), 1.2 * dt / 1000.0, np.float32)
+        refill[(t >= n_ticks // 3) & (t < 2 * n_ticks // 3)] *= 0.1
+        cap = np.full(2, 4.0, np.float32)
+        policy = strategy("final_adrr_olc")
+        wl = WorkloadConfig(n_requests=48, mix="balanced",
+                            congestion="medium")
+        batch, jitter = generate(jax.random.PRNGKey(0), wl)
+        phys = default_physics()
+        sim_cfg = SimConfig(n_ticks=n_ticks, k_slots=4, dt_ms=dt, window=64)
+        dyn = ProviderDynamics(comfort_scale=None,
+                               tb_refill=jnp.asarray(refill),
+                               tb_capacity=jnp.asarray(cap),
+                               retry_after_ms=jnp.float32(1500.0))
+        final, trace = jax.jit(lambda: run_sim(
+            policy, batch, jitter, phys, sim_cfg, dyn,
+            collect_decisions=True))()
+        prov = MockProvider(phys, dt_ms=dt, tb_refill=refill,
+                            tb_capacity=cap, retry_after_ms=1500.0)
+        sess = ClientSession(
+            prov, policy, SessionConfig(window=64, max_grants=4, dt_ms=dt),
+            clock="virtual", phys=phys)
+        for r in batch_to_requests(batch, jitter):
+            sess.submit(r)
+        assert_decision_parity(trace, *drive_session(sess, n_ticks))
+        assert int(final.provider.n_throttled) >= 1
+        assert sess.stats.n_throttled >= 1
+
     @pytest.mark.slow
     def test_heavy_high_overload_path(self):
         """Overload regime (arrivals compressed 3x): defers/rejects flow
@@ -193,7 +237,6 @@ class TestThrottleBackoff:
         """The crunch outlasts the shorts' stale timeout; relax the
         timeout multiple so the test isolates Retry-After behavior and
         post-crunch recovery from client-side abandonment."""
-        import jax.numpy as jnp
         return strategy("final_adrr_olc")._replace(
             timeout_mult=jnp.full((4,), 30.0, jnp.float32))
 
